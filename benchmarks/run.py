@@ -39,6 +39,8 @@ def main() -> None:
                     help="comma-separated subset of " + ",".join(BENCHES))
     args = ap.parse_args()
     names = list(BENCHES) if not args.only else args.only.split(",")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     os.makedirs(RESULTS_DIR, exist_ok=True)
     print("name,us_per_call,derived")

@@ -785,6 +785,8 @@ if __name__ == "__main__":
                          "total pool bytes, DESIGN.md §16) and makes "
                          "--trace-out export the merged fleet timeline")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out = run(tiny=args.tiny, kv=args.kv, reservation=args.reservation,
               kv_dtype=args.kv_dtype, step=args.step,
               trace_out=args.trace_out,

@@ -8,6 +8,7 @@ first-class argument.
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 
@@ -57,8 +58,12 @@ class SDPipeline:
         toks = F.null_tokens(batch, self.cfg.text_len)
         return F.encode_text(self.params["text"], self.text_cfg(), toks)
 
-    def eps_fn(self):
-        unet_params, cfg = self.params["unet"], self.cfg
+    def eps_fn(self, unet_params=None):
+        """The denoiser ``(latents, t, text) -> eps`` over ``unet_params``
+        (default: this pipeline's weights)."""
+        cfg = self.cfg
+        if unet_params is None:
+            unet_params = self.params["unet"]
 
         def fn(latents, t, text):
             return U.unet_forward(unet_params, cfg, latents, t, text)
@@ -88,16 +93,18 @@ class SDPipeline:
     def generate_jit(self, plan: GuidancePlan, *, stepper="ddim", eta=0.0,
                      **combine_kw):
         """Returns a jitted (cond_emb, uncond_emb, x0, rng) -> latents fn —
-        the measured object for the Table-1 latency benchmark."""
-        eps = self.eps_fn()
+        the measured object for the Table-1 latency benchmark. The UNet
+        weights enter the compiled program as an argument: closed over,
+        jit would fold them into it as constants."""
         sched = self.sched
 
         @jax.jit
-        def run(cond, uncond, x0, rng):
-            return sample(eps, plan, sched, x0, cond, uncond,
-                          stepper=stepper, eta=eta, rng=rng, **combine_kw)
+        def run(unet_params, cond, uncond, x0, rng):
+            return sample(self.eps_fn(unet_params), plan, sched, x0, cond,
+                          uncond, stepper=stepper, eta=eta, rng=rng,
+                          **combine_kw)
 
-        return run
+        return functools.partial(run, self.params["unet"])
 
     def timed_generate(self, prompts, plan: GuidancePlan, *, seed=0,
                        warmup: int = 2, iters: int = 5):

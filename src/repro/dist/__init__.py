@@ -1,22 +1,12 @@
-"""Distribution layer: jax version compat + logical-axis sharding rules.
+"""Distribution layer: logical-axis sharding rules (DESIGN.md §3)."""
 
-Importing this package installs the jax API backports (``compat.install``)
-so modern-sharding-API code runs on the pinned jax 0.4.37 — every module
-that shards anything imports from here, which makes the shim unconditional
-in practice.
-"""
-
-from repro.dist import compat
-
-compat.install()
-
-from repro.dist.sharding import (AxisRule, AxisRules, RULES_LONG,  # noqa: E402
-                                 RULES_SERVE, RULES_TRAIN, constrain,
+from repro.dist.sharding import (AxisRule, AxisRules, RULES_LONG, RULES_SERVE,
+                                 RULES_TRAIN, ambient_mesh, constrain,
                                  logical_to_spec, sanitize_spec,
                                  tree_shardings)
 
 __all__ = [
-    "compat", "AxisRule", "AxisRules", "RULES_LONG", "RULES_SERVE",
-    "RULES_TRAIN", "constrain", "logical_to_spec", "sanitize_spec",
+    "AxisRule", "AxisRules", "RULES_LONG", "RULES_SERVE", "RULES_TRAIN",
+    "ambient_mesh", "constrain", "logical_to_spec", "sanitize_spec",
     "tree_shardings",
 ]

@@ -40,8 +40,6 @@ from typing import Mapping
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
-
 # Logical names without a rule entry (and ``None`` placeholders) replicate.
 DEFAULT_PRIORITY = 9
 
@@ -285,21 +283,24 @@ def tree_shardings(axes_tree, specs_tree, mesh, rules: AxisRules):
     return jax.tree.map(one, axes_tree, specs_tree, is_leaf=is_axes_leaf)
 
 
+def ambient_mesh():
+    """The mesh ``jax.set_mesh`` made ambient, or ``None`` outside one
+    (jax reports "no mesh" as an empty ``AbstractMesh``)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
+
+
 def constrain(x, logical, rules: AxisRules | None):
     """Sharding hint against the ambient mesh (no-op without one).
 
-    Inside ``jit`` under a mesh context this pins the layout GSPMD must
+    Inside ``jit`` under ``jax.set_mesh`` this pins the layout GSPMD must
     propagate; outside any mesh (unit tests, single-host runs) it returns
-    ``x`` unchanged. Concrete meshes get a ``NamedSharding`` (works under
-    both the legacy resource env and the modern context manager); abstract
-    meshes get the bare spec.
+    ``x`` unchanged.
     """
     if rules is None:
         return x
-    mesh = compat.get_abstract_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         return x
     spec = logical_to_spec(logical, rules, shape=x.shape, mesh=mesh)
-    if isinstance(mesh, jax.sharding.Mesh):
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     return jax.lax.with_sharding_constraint(x, spec)

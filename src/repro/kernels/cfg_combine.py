@@ -10,8 +10,9 @@ DESIGN.md §15):
 * ``apg_combine_pallas`` — APG normalized/projected guidance (arxiv
   2410.02416): the cond/uncond difference is norm-clamped, split into
   components parallel/orthogonal to the conditional prediction, and only
-  the orthogonal part guides at full strength.  One row per grid step so
-  the row reductions (norm, dot) stay inside a single VMEM block.
+  the orthogonal part guides at full strength.  Two passes over feature
+  blocks: the first accumulates the per-row norms and dot, the second
+  writes.
 * ``cfg_combine_rowscale_pallas`` — Eq. 1 with a *per-row* scale, the fused
   form of interval guidance (arxiv 2404.07724) where rows outside the
   guidance interval run at scale 1.
@@ -31,8 +32,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-12   # guards 0-norm rows (ragged padding); 0-diff rows stay exact
+_BLOCK_FEAT = 2048   # feature lanes per block of the row-wise combines
 
 
 def _interpret_default(interpret: bool | None) -> bool:
@@ -118,53 +121,88 @@ def apg_combine_ref(eps_uncond, eps_cond, scale, *, eta: float = 0.0,
     return (c + (scale - 1.0) * (d_orth + eta * d_par)).astype(eps_cond.dtype)
 
 
-def _apg_kernel(u_ref, c_ref, o_ref, *, scale: float, eta: float,
-                threshold: float):
+def _row_blocks(rows: int, feat: int):
+    """Tile a (rows, feat) view for Mosaic: the row block is the full row
+    extent when it is at most 8 (a block dim equal to the array dim is
+    legal) and 8 otherwise; the feature block is a multiple of 128 lanes.
+    Returns (row block, padded rows, feature block, padded features)."""
+    br = rows if rows <= 8 else 8
+    bf = min(pl.cdiv(feat, 128) * 128, _BLOCK_FEAT)
+    return br, pl.cdiv(rows, br) * br, bf, pl.cdiv(feat, bf) * bf
+
+
+def _pad2(x, rows: int, feat: int):
+    return jnp.pad(x, ((0, rows - x.shape[0]), (0, feat - x.shape[1])))
+
+
+def _apg_kernel(u_ref, c_ref, o_ref, dd_ref, cc_ref, dc_ref, *, scale: float,
+                eta: float, threshold: float):
+    """Grid (row block, pass, feature block). Pass 0 accumulates the three
+    per-row sums (|d|^2, |c|^2, d.c) over the feature blocks; pass 1
+    applies the clamp and projection from them. The output block index
+    stays put through pass 0, so only pass 1's values are written back."""
+    p, j = pl.program_id(1), pl.program_id(2)
     u = u_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
     d = c - u
-    if threshold > 0.0:
-        d_norm = jnp.sqrt(jnp.sum(d * d))
-        d = d * jnp.minimum(1.0, threshold / jnp.maximum(d_norm, _EPS))
-    c_norm = jnp.sqrt(jnp.sum(c * c))
-    v1 = c / jnp.maximum(c_norm, _EPS)
-    d_par = jnp.sum(d * v1) * v1
-    o_ref[...] = (c + (scale - 1.0) * ((d - d_par) + eta * d_par)
-                  ).astype(o_ref.dtype)
+
+    @pl.when((p == 0) & (j == 0))
+    def _():
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+        cc_ref[...] = jnp.zeros_like(cc_ref)
+        dc_ref[...] = jnp.zeros_like(dc_ref)
+
+    @pl.when(p == 0)
+    def _():
+        dd_ref[...] += jnp.sum(d * d, axis=1, keepdims=True)
+        cc_ref[...] += jnp.sum(c * c, axis=1, keepdims=True)
+        dc_ref[...] += jnp.sum(d * c, axis=1, keepdims=True)
+
+    @pl.when(p == 1)
+    def _():
+        k = 1.0
+        if threshold > 0.0:
+            k = jnp.minimum(1.0, threshold
+                            / jnp.maximum(jnp.sqrt(dd_ref[...]), _EPS))
+        c_norm = jnp.maximum(jnp.sqrt(cc_ref[...]), _EPS)
+        dk = d * k
+        d_par = (k * dc_ref[...] / c_norm) * (c / c_norm)
+        o_ref[...] = (c + (scale - 1.0) * ((dk - d_par) + eta * d_par)
+                      ).astype(o_ref.dtype)
 
 
 def apg_combine_pallas(eps_uncond, eps_cond, scale: float, *,
                        eta: float = 0.0, threshold: float = 0.0,
                        interpret: bool | None = None):
-    """Fused APG combine.  One grid step per batch row: the whole feature
-    row sits in one VMEM block so the norm/dot reductions need no
-    cross-block accumulation; lane padding is zero-filled, which perturbs
-    neither sums nor dots."""
+    """Fused APG combine over a (rows, features) view, tiled in both axes
+    (``_row_blocks``) so any row count and feature width fits VMEM. The
+    per-row norm and dot reductions accumulate in scratch across feature
+    blocks before the second pass writes; zero padding perturbs neither
+    sums nor dots."""
     assert eps_uncond.shape == eps_cond.shape
     orig_shape = eps_cond.shape
     u2, c2 = _as_rows(eps_uncond), _as_rows(eps_cond)
     rows, feat = c2.shape
-    lanes = 128
-    fp = pl.cdiv(feat, lanes) * lanes
-    u2 = jnp.pad(u2, ((0, 0), (0, fp - feat)))
-    c2 = jnp.pad(c2, ((0, 0), (0, fp - feat)))
+    br, rp, bf, fp = _row_blocks(rows, feat)
+    u2, c2 = _pad2(u2, rp, fp), _pad2(c2, rp, fp)
+    blk = pl.BlockSpec((br, bf), lambda i, p, j: (i, j))
     out = pl.pallas_call(
         functools.partial(_apg_kernel, scale=float(scale), eta=float(eta),
                           threshold=float(threshold)),
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, fp), lambda i: (i, 0)),
-                  pl.BlockSpec((1, fp), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, fp), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, fp), eps_cond.dtype),
+        grid=(rp // br, 2, fp // bf),
+        in_specs=[blk, blk],
+        out_specs=pl.BlockSpec((br, bf), lambda i, p, j: (i, j * p)),
+        out_shape=jax.ShapeDtypeStruct((rp, fp), eps_cond.dtype),
+        scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32)] * 3,
         interpret=_interpret_default(interpret),
     )(u2, c2)
-    return out[:, :feat].reshape(orig_shape)
+    return out[:rows, :feat].reshape(orig_shape)
 
 
 def _rowscale_kernel(u_ref, c_ref, s_ref, o_ref):
     u = u_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
-    s = s_ref[0, 0].astype(jnp.float32)
+    s = s_ref[:, :1]
     o_ref[...] = (u + s * (c - u)).astype(o_ref.dtype)
 
 
@@ -178,19 +216,18 @@ def cfg_combine_rowscale_pallas(eps_uncond, eps_cond, scales, *,
     u2, c2 = _as_rows(eps_uncond), _as_rows(eps_cond)
     rows, feat = c2.shape
     assert scales.shape == (rows,), (scales.shape, rows)
+    br, rp, bf, fp = _row_blocks(rows, feat)
+    u2, c2 = _pad2(u2, rp, fp), _pad2(c2, rp, fp)
     lanes = 128
-    fp = pl.cdiv(feat, lanes) * lanes
-    u2 = jnp.pad(u2, ((0, 0), (0, fp - feat)))
-    c2 = jnp.pad(c2, ((0, 0), (0, fp - feat)))
-    s2 = jnp.broadcast_to(scales.astype(jnp.float32)[:, None], (rows, lanes))
+    s2 = jnp.broadcast_to(_pad2(scales.astype(jnp.float32)[:, None], rp, 1),
+                          (rp, lanes))
+    blk = pl.BlockSpec((br, bf), lambda i, j: (i, j))
     out = pl.pallas_call(
         _rowscale_kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, fp), lambda i: (i, 0)),
-                  pl.BlockSpec((1, fp), lambda i: (i, 0)),
-                  pl.BlockSpec((1, lanes), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, fp), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, fp), eps_cond.dtype),
+        grid=(rp // br, fp // bf),
+        in_specs=[blk, blk, pl.BlockSpec((br, lanes), lambda i, j: (i, 0))],
+        out_specs=blk,
+        out_shape=jax.ShapeDtypeStruct((rp, fp), eps_cond.dtype),
         interpret=_interpret_default(interpret),
     )(u2, c2, s2)
-    return out[:, :feat].reshape(orig_shape)
+    return out[:rows, :feat].reshape(orig_shape)

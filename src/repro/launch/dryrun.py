@@ -24,15 +24,14 @@ from repro.configs import SHAPES, get_config, list_archs              # noqa: E4
 from repro.launch import steps as ST                                  # noqa: E402
 from repro.launch.mesh import chips, make_production_mesh             # noqa: E402
 from repro import roofline as RL                                      # noqa: E402
-from repro.dist.compat import cost_analysis, use_mesh                  # noqa: E402
 
 
 def _custom_mesh(spec: str):
     axes_s, _, shape_s = spec.partition("=")
     axes = tuple(axes_s.split(","))
     shape = tuple(int(x) for x in shape_s.split(","))
-    from repro.dist.compat import AxisType, make_mesh
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
@@ -53,7 +52,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     t0 = time.time()
     try:
         bundle = ST.build(cfg, shape, mesh, variant=variant)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(bundle.fn,
                               in_shardings=bundle.in_shardings,
                               out_shardings=bundle.out_shardings,
@@ -71,11 +70,11 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             try:
                 if not multi_pod:
                     cost_bundle = ST.build(cfg, shape, mesh, variant=variant)
-                    ca = cost_analysis(jax.jit(
+                    ca = jax.jit(
                         cost_bundle.fn, in_shardings=cost_bundle.in_shardings,
                         out_shardings=cost_bundle.out_shardings,
                         donate_argnums=cost_bundle.donate
-                        ).lower(*cost_bundle.in_specs))
+                        ).lower(*cost_bundle.in_specs).cost_analysis() or {}
                     cost = {"flops": float(ca.get("flops", 0.0)) / chips(mesh),
                             "bytes": float(ca.get("bytes accessed", 0.0))
                             / chips(mesh)}
@@ -99,7 +98,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             print(f"[ok] {bundle.name} mesh={rec['mesh']} "
                   f"compile={rec['compile_s']}s", flush=True)
             print(f"     memory_analysis: {mem}", flush=True)
-            ca = cost_analysis(compiled)
+            ca = compiled.cost_analysis() or {}
             print(f"     cost_analysis: flops={ca.get('flops', 0):.3e} "
                   f"bytes={ca.get('bytes accessed', 0):.3e}", flush=True)
             print(f"     roofline: compute={rl.compute_s:.3e}s "
@@ -123,7 +122,7 @@ def run_sd(*, multi_pod: bool = False, variant: str = "full",
     t0 = time.time()
     try:
         bundle = ST.build_sd_denoise(mesh, variant=variant)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                                out_shardings=bundle.out_shardings,
                                donate_argnums=bundle.donate
@@ -140,7 +139,7 @@ def run_sd(*, multi_pod: bool = False, variant: str = "full",
         if verbose:
             print(f"[ok] {bundle.name} mesh={rec['mesh']} "
                   f"compile={rec['compile_s']}s", flush=True)
-            ca = cost_analysis(compiled)
+            ca = compiled.cost_analysis() or {}
             print(f"     cost_analysis: flops={ca.get('flops', 0):.3e} "
                   f"bytes={ca.get('bytes accessed', 0):.3e}", flush=True)
             print(f"     memory: args={mem.argument_size_in_bytes/1e9:.2f}GB "

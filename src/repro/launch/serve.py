@@ -23,6 +23,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.prompts import PAPER_PROMPTS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.serve import (ContinuousEngine, ServeFleet, ServeRequest,
@@ -52,7 +53,9 @@ def run_static(params, cfg, args) -> None:
         print(f"           sample[{sample_uid}]: {out[sample_uid][:16]}")
 
 
-def _make_engine(params, cfg, args) -> ContinuousEngine:
+def _make_engine(params, cfg, args, **overrides) -> ContinuousEngine:
+    """The continuous engine ``args`` describe; ``overrides`` (such as
+    ``mesh=`` or ``num_pages=``) pass straight to the constructor."""
     budget = "auto" if args.pass_budget == "auto" \
         else (int(args.pass_budget) or 2 * args.batch)
     swap_min = args.swap_min_pages if args.swap_min_pages == "auto" \
@@ -75,7 +78,7 @@ def _make_engine(params, cfg, args) -> ContinuousEngine:
                             divergence_threshold=args.divergence_threshold,
                             interval=tuple(args.interval),
                             tick_mode="async" if args.async_ticks
-                            else "sync")
+                            else "sync", **overrides)
 
 
 def _trace_requests(args) -> tuple[list[ServeRequest], list[float]]:
@@ -189,7 +192,7 @@ def run_continuous(params, cfg, args) -> None:
           f"(equal pass budget {budget})")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -284,7 +287,11 @@ def main() -> None:
                     help="selective-guidance optimized fraction (paper: 0.2)")
     ap.add_argument("--guidance-scale", type=float, default=4.0)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
+
+
+def check_args(ap: argparse.ArgumentParser, args) -> None:
+    """Refuse option combinations the engine cannot serve."""
 
     if args.reservation == "lazy" and args.kv != "paged":
         ap.error("--reservation lazy requires --kv paged "
@@ -318,6 +325,13 @@ def main() -> None:
     if args.async_ticks and args.policy != "static":
         ap.error("--async-ticks requires --policy static (dynamic "
                  "switches read divergence mid-tick)")
+
+
+def main() -> None:
+    ap = build_parser()
+    args = ap.parse_args()
+    check_args(ap, args)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -300,9 +300,10 @@ def attn_decode_ring(params, cfg, x, cache, pos, *, window: int):
 
 
 def _paged_kernel() -> bool:
-    """REPRO_PAGED_ATTN=pallas: route paged decode attention through the
-    block-table Pallas kernel instead of the jnp gather oracle."""
-    return os.environ.get("REPRO_PAGED_ATTN") == "pallas"
+    """Paged decode attention runs the compiled block-table Pallas kernels
+    on a TPU and the jnp gather form everywhere else (the CPU has no
+    Mosaic backend, and interpreting the kernels there is a test tool)."""
+    return jax.default_backend() == "tpu"
 
 
 def paged_cache_spec(cfg, mk, num_pages: int, page_size: int,
@@ -342,7 +343,7 @@ def paged_cache_spec(cfg, mk, num_pages: int, page_size: int,
 
 
 def attn_decode_paged(params, cfg, x, pool, block_table, pos, *,
-                      window=None, phase=None):
+                      window=None, phase=None, kernel=None):
     """One token per row vs the shared paged KV pool.
 
     x (B,1,D); pool {k,v: (P, page_size, K, hd)} — shared across every
@@ -356,16 +357,21 @@ def attn_decode_paged(params, cfg, x, pool, block_table, pos, *,
     list** (DESIGN.md §12): rows with ``phase == 0`` are padding whose
     attention output is exactly zero (their block tables are all
     out-of-range, so their writes drop too), live rows are unchanged.
-    Under ``REPRO_PAGED_ATTN=pallas`` the ragged kernels additionally
-    skip the dead rows' page DMA and FLOPs inside the launch.
+    On the kernel path the ragged kernels additionally skip the dead
+    rows' page DMA and FLOPs inside the launch.
+
+    ``kernel`` picks the attention form: ``None`` follows the platform
+    (:func:`_paged_kernel`), ``False`` forces the gather form — the
+    choice for a step partitioned over several devices, since Mosaic
+    kernels cannot be partitioned automatically.
 
     Returns (out (B,1,D), updated pool). The new K/V is scattered into
     the row's current page before attention, so the semantics match
     ``attn_decode`` exactly on the covered positions. An int8 pool
     (``k_scale`` leaves present, DESIGN.md §11) quantizes on write —
     the one-row append quantizes just the new position, never touching
-    already-written rows — and dequantizes on read, fused in-kernel
-    under ``REPRO_PAGED_ATTN=pallas``.
+    already-written rows — and dequantizes on read, fused in-kernel on
+    the kernel path.
     """
     B = x.shape[0]
     P, ps = pool["k"].shape[:2]
@@ -389,7 +395,7 @@ def attn_decode_paged(params, cfg, x, pool, block_table, pos, *,
                     "v": put(pool["v"], v_new[:, 0])}
     qg = _group(q, cfg.num_kv_heads)                 # (B,1,K,rep,hd)
     hd = q.shape[-1]
-    if _paged_kernel():
+    if _paged_kernel() if kernel is None else kernel:
         from repro.kernels import paged_decode_attention as PDA
         interpret = jax.default_backend() != "tpu"
         if phase is not None and quant:

@@ -186,21 +186,23 @@ def block_decode(params, cfg, kind, x, cache, pos, *, moe_layer: bool,
 
 
 def block_decode_paged(params, cfg, kind, x, pool, block_table, pos, *,
-                       moe_layer: bool, long_ctx: bool = False, phase=None):
+                       moe_layer: bool, long_ctx: bool = False, phase=None,
+                       kernel=None):
     """One-token step per row against the shared paged KV pool.
 
     Only attention caches page (KV grows with the sequence); recurrent /
     xLSTM state is O(1) per request and MLA latents keep their own layout,
     so paged serving is restricted to plain GQA attention stacks —
     enforced structurally by :func:`paged_cache_specs`.
-    ``phase`` marks a ragged pass list (DESIGN.md §12; see
+    ``phase`` marks a ragged pass list (DESIGN.md §12) and ``kernel``
+    picks the attention form (see
     :func:`repro.models.attention.attn_decode_paged`).
     """
     h = _norm(cfg, params["norm1"], x)
     window = _window(cfg, kind, long_ctx)
     mix, pool = A.attn_decode_paged(params["attn"], cfg, h, pool,
                                     block_table, pos, window=window,
-                                    phase=phase)
+                                    phase=phase, kernel=kernel)
     x = x + mix
     if "mlp" in params:
         h2 = _norm(cfg, params["norm2"], x)
@@ -321,7 +323,7 @@ def paged_cache_specs(cfg, mk, num_pages: int, page_size: int,
 
 
 def decode_step_paged(params, cfg, token_embeds, pools, block_table, pos, *,
-                      rules=None, long_ctx=False, phase=None):
+                      rules=None, long_ctx=False, phase=None, kernel=None):
     """One-token step for the whole stack against paged KV pools.
 
     token_embeds (B,1,D); ``pools`` from :func:`paged_cache_specs`;
@@ -330,7 +332,8 @@ def decode_step_paged(params, cfg, token_embeds, pools, block_table, pos, *,
     ``phase`` (B,) int32, when given, marks the batch as a ragged pass
     list: rows with ``phase == 0`` are padding (zero attention output,
     dropped writes) — the fixed-shape contract the serving engine's
-    single-compile step relies on (DESIGN.md §12).
+    single-compile step relies on (DESIGN.md §12). ``kernel`` picks the
+    attention form (:func:`repro.models.attention.attn_decode_paged`).
     Returns (hidden (B,1,D), new pools).
     """
     x = token_embeds
@@ -344,7 +347,8 @@ def decode_step_paged(params, cfg, token_embeds, pools, block_table, pos, *,
             moe_layer = _is_moe_layer(cfg, seen < leading_dense)
             x, p = block_decode_paged(seg_params, cfg, seg[1], x, seg_pool,
                                       block_table, pos, moe_layer=moe_layer,
-                                      long_ctx=long_ctx, phase=phase)
+                                      long_ctx=long_ctx, phase=phase,
+                                      kernel=kernel)
             new_pools.append(p)
             seen += 1
         else:
@@ -359,7 +363,7 @@ def decode_step_paged(params, cfg, token_embeds, pools, block_table, pos, *,
                                                block_table, pos,
                                                moe_layer=moe_layer,
                                                long_ctx=long_ctx,
-                                               phase=phase)
+                                               phase=phase, kernel=kernel)
                     new_ps.append(p2)
                 return x, new_ps
 

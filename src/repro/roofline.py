@@ -24,7 +24,6 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-from repro.dist.compat import cost_analysis
 from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 
 _DTYPE_BYTES = {
@@ -134,7 +133,7 @@ def analyze(name: str, compiled, chips: int, model_flops: float = 0.0, *,
     if cost is not None:
         flops, byts = cost["flops"], cost["bytes"]
     else:
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         flops = float(ca.get("flops", 0.0))
         byts = float(ca.get("bytes accessed", 0.0))
     if supplement:

@@ -393,6 +393,11 @@ class ContinuousEngine:
             rules = RULES_SERVE
         self.rules = rules
         self.mesh = mesh
+        # paged attention form: the platform decides (compiled Pallas on a
+        # TPU), except on a mesh-placed arena, whose step the partitioner
+        # splits over the mesh — Mosaic kernels cannot be partitioned
+        # automatically, so that step takes the gather form
+        self._attn_kernel = False if mesh is not None else None
         self.tick_mode = tick_mode
         self.stop_on_eos = stop_on_eos
         self.guidance_policy = guidance_policy
@@ -1397,9 +1402,6 @@ class ContinuousEngine:
 
     # -- jitted device functions ------------------------------------------
 
-    def _donate(self, *argnums):
-        return argnums if jax.default_backend() != "cpu" else ()
-
     def _init_pools(self) -> None:
         S, cap, cfg = self.prompt_len, self.capacity, self.cfg
 
@@ -1433,21 +1435,22 @@ class ContinuousEngine:
         specs = T.paged_cache_specs(self.cfg, L.SpecMaker(jnp.bfloat16),
                                     self.num_pages, self.page_size,
                                     kv_dtype=self.kv_dtype)
+        zeros = lambda: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                     specs)
         if self.mesh is not None and self.rules is not None:
             # land the arena on the mesh at construction: values, int8
             # fp32 scale leaves and block-table-indexed rows all shard
             # along `pages` (per-shard counts uniform by the ctor's
             # divisibility rounding; indivisible explicit pools fall down
-            # the logical_to_spec fallback chain to replication)
+            # the logical_to_spec fallback chain to replication). The
+            # zeros are made in place, shard by shard: the whole pool never
+            # lands on one device first.
             shardings = paged_pool_shardings(
                 self.cfg, self.num_pages, self.page_size,
                 rules=self.rules, mesh=self.mesh, kv_dtype=self.kv_dtype)
-            self._pool_p = jax.tree.map(
-                lambda s, sh: jax.device_put(jnp.zeros(s.shape, s.dtype), sh),
-                specs, shardings)
+            self._pool_p = jax.jit(zeros, out_shardings=shardings)()
             return
-        self._pool_p = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), specs)
+        self._pool_p = zeros()
 
     def _prefill_fn(self):
         # pow2-padded length bucket key: the slot engine serves one fixed
@@ -1470,7 +1473,7 @@ class ContinuousEngine:
             pool_u = jax.tree.map(lambda p, r: p.at[slot].set(r), pool_u, cu)
             return pool_c, pool_u, tok0[0]
 
-        self._jit[key] = jax.jit(fn, donate_argnums=self._donate(1, 2))
+        self._jit[key] = jax.jit(fn, donate_argnums=(1, 2))
         return self._jit[key]
 
     def _paged_prefill_fn(self, Sb: int, kb: int):
@@ -1530,7 +1533,7 @@ class ContinuousEngine:
             # can deposit them as replayable payloads
             return pool, tok0, l_c, l_u
 
-        self._jit[key] = jax.jit(fn, donate_argnums=self._donate(1))
+        self._jit[key] = jax.jit(fn, donate_argnums=(1,))
         return self._jit[key]
 
     def _step_fn(self, n_full: int, n_cond: int):
@@ -1586,7 +1589,7 @@ class ContinuousEngine:
             # out[0]/out[1] pool indices stay stable
             return pool_c, pool_u, f_next, c_next, f_div
 
-        self._jit[key] = jax.jit(fn, donate_argnums=self._donate(1, 2))
+        self._jit[key] = jax.jit(fn, donate_argnums=(1, 2))
         return self._jit[key]
 
     def _paged_step_fn(self, n_full: int, n_cond: int):
@@ -1598,7 +1601,7 @@ class ContinuousEngine:
         if key in self._jit:
             return self._jit[key]
         self.metrics.on_step_compile(self.tick_count)
-        cfg, rules = self.cfg, self.rules
+        cfg, rules, kernel = self.cfg, self.rules, self._attn_kernel
 
         def sample_rows(logits, keys, temps, lsteps):
             def one(lg, k, t, ls):
@@ -1613,9 +1616,11 @@ class ContinuousEngine:
             if n_full:
                 emb = T.embed_tokens(params, cfg, f_tok[:, None])
                 h_c, pool = T.decode_step_paged(params, cfg, emb, pool,
-                                                f_btc, f_pos, rules=rules)
+                                                f_btc, f_pos, rules=rules,
+                                                kernel=kernel)
                 h_u, pool = T.decode_step_paged(params, cfg, emb, pool,
-                                                f_btu, f_pos, rules=rules)
+                                                f_btu, f_pos, rules=rules,
+                                                kernel=kernel)
                 l_c = T.unembed(params, cfg, h_c)[:, 0, :].astype(jnp.float32)
                 l_u = T.unembed(params, cfg, h_u)[:, 0, :].astype(jnp.float32)
                 logits = self._combine(l_u, l_c, f_scale[:, None])
@@ -1624,13 +1629,14 @@ class ContinuousEngine:
             if n_cond:
                 emb = T.embed_tokens(params, cfg, c_tok[:, None])
                 h_c, pool = T.decode_step_paged(params, cfg, emb, pool,
-                                                c_btc, c_pos, rules=rules)
+                                                c_btc, c_pos, rules=rules,
+                                                kernel=kernel)
                 logits = T.unembed(params, cfg, h_c)[:, 0, :].astype(jnp.float32)
                 c_next = sample_rows(logits, c_key, c_temp, c_lstep)
             # f_div rides at the END: the autotuner's out[0] stays the pool
             return pool, f_next, c_next, f_div
 
-        self._jit[key] = jax.jit(fn, donate_argnums=self._donate(1))
+        self._jit[key] = jax.jit(fn, donate_argnums=(1,))
         return self._jit[key]
 
     def _ragged_step_fn(self):
@@ -1651,13 +1657,14 @@ class ContinuousEngine:
         if key in self._jit:
             return self._jit[key]
         self.metrics.on_step_compile(self.tick_count)
-        cfg, rules = self.cfg, self.rules
+        cfg, rules, kernel = self.cfg, self.rules, self._attn_kernel
 
         def fn(params, pool, bt, tok, pos, scale, temp, rkey, lstep, u_idx,
                phase):
             emb = T.embed_tokens(params, cfg, tok[:, None])
             h, pool = T.decode_step_paged(params, cfg, emb, pool, bt, pos,
-                                          rules=rules, phase=phase)
+                                          rules=rules, phase=phase,
+                                          kernel=kernel)
             logits = T.unembed(params, cfg, h)[:, 0, :].astype(jnp.float32)
             combined = self._combine(logits[u_idx], logits, scale[:, None])
 
@@ -1671,7 +1678,7 @@ class ContinuousEngine:
             div = jnp.sqrt(jnp.sum((logits - logits[u_idx]) ** 2, axis=-1))
             return pool, nxt, div
 
-        self._jit[key] = jax.jit(fn, donate_argnums=self._donate(1))
+        self._jit[key] = jax.jit(fn, donate_argnums=(1,))
         return self._jit[key]
 
     def _defrag_fn(self):
@@ -1680,7 +1687,7 @@ class ContinuousEngine:
             def fn(pool_c, pool_u, src):
                 take = lambda a: a[src]
                 return jax.tree.map(take, pool_c), jax.tree.map(take, pool_u)
-            self._jit[key] = jax.jit(fn, donate_argnums=self._donate(0, 1))
+            self._jit[key] = jax.jit(fn, donate_argnums=(0, 1))
         return self._jit[key]
 
     def _copy_page_fn(self):
@@ -1695,7 +1702,7 @@ class ContinuousEngine:
                         return leaf.at[:, dst].set(leaf[:, src])
                     return leaf.at[dst].set(leaf[src])
                 return jax.tree.map(one, pool)
-            self._jit[key] = jax.jit(fn, donate_argnums=self._donate(0))
+            self._jit[key] = jax.jit(fn, donate_argnums=(0,))
         return self._jit[key]
 
     def _gather_pages_fn(self, nb: int):
@@ -1722,7 +1729,7 @@ class ContinuousEngine:
                         return leaf.at[:, idx].set(r, mode="drop")
                     return leaf.at[idx].set(r, mode="drop")
                 return jax.tree.map(one, pool, rows)
-            self._jit[key] = jax.jit(fn, donate_argnums=self._donate(0))
+            self._jit[key] = jax.jit(fn, donate_argnums=(0,))
         return self._jit[key]
 
     def _hit_sample_fn(self):

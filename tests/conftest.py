@@ -5,15 +5,8 @@ _ROOT = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, _ROOT)                       # benchmarks.* imports
 sys.path.insert(0, os.path.join(_ROOT, "src"))  # repro.* without PYTHONPATH
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # pinned container has no hypothesis: use the stub
-    sys.path.append(os.path.join(os.path.dirname(__file__), "_stubs"))
-
 import jax
 import pytest
-
-import repro.dist  # noqa: F401  — installs the jax version-compat shims
 
 # Tests run on the single real CPU device (the dry-run manages its own
 # 512-device world in a separate process). Keep x64 off (TPU-realistic).

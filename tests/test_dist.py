@@ -1,5 +1,5 @@
-"""Distribution layer beyond the allocator: compat shims, rule overrides,
-tree_shardings and the ambient-mesh constrain helper.
+"""Distribution layer beyond the allocator: the ambient mesh, rule
+overrides, tree_shardings and the ambient-mesh constrain helper.
 
 (The allocator semantics themselves are pinned by ``test_sharding.py``.)
 """
@@ -8,12 +8,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
 from repro.dist.sharding import (AxisRule, AxisRules, RULES_SERVE,
-                                 RULES_TRAIN, constrain, logical_to_spec,
-                                 sanitize_spec, tree_shardings)
+                                 RULES_TRAIN, ambient_mesh, constrain,
+                                 logical_to_spec, sanitize_spec,
+                                 tree_shardings)
 from repro.launch.mesh import make_host_mesh
 
 
@@ -24,52 +24,40 @@ def mesh():
 
 @pytest.fixture(scope="module")
 def abstract():
-    return compat.abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------
-# compat
+# ambient mesh / host mesh
 # ---------------------------------------------------------------------------
 
 
 def test_get_abstract_mesh_none_outside_context():
-    assert compat.get_abstract_mesh() is None
+    assert ambient_mesh() is None
 
 
 def test_get_abstract_mesh_sees_ambient_mesh(mesh):
-    with compat.use_mesh(mesh):
-        m = compat.get_abstract_mesh()
+    with jax.set_mesh(mesh):
+        m = ambient_mesh()
         assert m is not None
         assert tuple(m.axis_names) == ("data", "model")
         assert dict(m.shape) == dict(mesh.shape)
-    assert compat.get_abstract_mesh() is None
+    assert ambient_mesh() is None
 
 
-def test_abstract_mesh_builder(abstract):
-    assert tuple(abstract.axis_names) == ("data", "model")
-    assert dict(abstract.shape) == {"data": 16, "model": 16}
+def test_host_mesh_refuses_more_devices_than_exist():
+    """An unbuildable mesh is an error, never a silently smaller mesh."""
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="devices"):
+        make_host_mesh(data=n + 1)
+    with pytest.raises(ValueError, match="devices"):
+        make_host_mesh(data=n, model=2)
 
 
-def test_jax_sharding_namespace_is_modern():
-    """After the shim install, modern-API code paths exist on any jax."""
-    from jax.sharding import AbstractMesh, AxisType
-    m = AbstractMesh((4, 2), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
-    assert dict(m.shape) == {"data": 4, "model": 2}
-    assert jax.sharding.get_abstract_mesh is not None
-
-
-def test_make_mesh_accepts_axis_types():
-    m = compat.make_mesh((1, 1), ("data", "model"),
-                         axis_types=(compat.AxisType.Auto,) * 2)
-    assert tuple(m.axis_names) == ("data", "model")
-
-
-def test_install_idempotent():
-    before = (jax.sharding.AbstractMesh, jax.sharding.AxisType)
-    compat.install()
-    compat.install()
-    assert (jax.sharding.AbstractMesh, jax.sharding.AxisType) == before
+def test_host_mesh_takes_the_first_devices():
+    m = make_host_mesh(data=1, model=1)
+    assert dict(m.shape) == {"data": 1, "model": 1}
+    assert list(m.devices.flat) == jax.devices()[:1]
 
 
 # ---------------------------------------------------------------------------
@@ -178,5 +166,5 @@ def test_constrain_under_mesh_preserves_values(mesh):
     def f(x):
         return constrain(x, ("batch", "seq"), RULES_TRAIN) * 2
 
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x) * 2)

@@ -284,8 +284,8 @@ def test_attn_decode_paged_matches_linear_cache():
 
 
 def test_attn_decode_paged_pallas_route_matches_jnp(monkeypatch):
-    """REPRO_PAGED_ATTN=pallas routes the model path through the kernel
-    with identical semantics (writes included)."""
+    """The kernel path (the TPU's choice; interpreted here) has the
+    gather form's semantics, writes included."""
     cfg = get_smoke_config("llama3.2-1b")
     p = A.init_attention(cfg, L.ArrayMaker(jax.random.PRNGKey(0)))
     B, ps, nbr = 2, 4, 4
@@ -298,8 +298,9 @@ def test_attn_decode_paged_pallas_route_matches_jnp(monkeypatch):
     bt = jnp.asarray(rng.permutation(P_)[: B * nbr]
                      .reshape(B, nbr).astype(np.int32))
     pos = jnp.asarray([6, 11], jnp.int32)
+    monkeypatch.setattr(A, "_paged_kernel", lambda: False)
     out_jnp, pool_jnp = A.attn_decode_paged(p, cfg, x, pool, bt, pos)
-    monkeypatch.setenv("REPRO_PAGED_ATTN", "pallas")
+    monkeypatch.setattr(A, "_paged_kernel", lambda: True)
     out_pl, pool_pl = A.attn_decode_paged(p, cfg, x, pool, bt, pos)
     np.testing.assert_allclose(np.asarray(out_pl), np.asarray(out_jnp),
                                rtol=3e-5, atol=3e-5)

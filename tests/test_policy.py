@@ -257,6 +257,29 @@ def test_rowscale_kernel_matches_per_row_eq1(rows, feat, seed):
         np.asarray(c), rtol=2e-6, atol=2e-6)
 
 
+@pytest.mark.parametrize("rows,feat", [(2, 2049), (8, 4500), (13, 260),
+                                       (20, 4097)])
+def test_combine_kernels_tile_rows_and_features(rows, feat):
+    """Several row blocks of 8 and several feature blocks: the
+    APG reductions accumulate across feature blocks, padded rows and
+    lanes are sliced off, and both kernels still match their oracles."""
+    rng = jax.random.PRNGKey(rows * 1000 + feat)
+    u = jax.random.normal(rng, (rows, feat), jnp.float32)
+    c = jax.random.normal(jax.random.fold_in(rng, 1), (rows, feat),
+                          jnp.float32)
+    for threshold in (0.0, 2.5):
+        out = apg_combine_pallas(u, c, 4.0, eta=0.3, threshold=threshold,
+                                 interpret=True)
+        ref = apg_combine_ref(u, c, 4.0, eta=0.3, threshold=threshold)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+    scales = jnp.linspace(0.5, 8.0, rows, dtype=jnp.float32)
+    out = cfg_combine_rowscale_pallas(u, c, scales, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(u + scales[:, None] * (c - u)),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # Checkpoint-state reclaim regressions (simulator, no model)
 # ---------------------------------------------------------------------------

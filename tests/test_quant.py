@@ -147,9 +147,9 @@ def test_int8_kernel_matches_oracle(seed, window):
 
 
 def test_attn_decode_paged_int8_pallas_matches_jnp(monkeypatch):
-    """REPRO_PAGED_ATTN=pallas routes the int8 model path through the
-    fused kernel; outputs and the written pool pages (values + scales)
-    match the jnp dequantizing path."""
+    """The kernel path (the TPU's choice; interpreted here) runs the int8
+    model path through the fused kernel; outputs and the written pool
+    pages (values + scales) match the jnp dequantizing path."""
     cfg = get_smoke_config("llama3.2-1b")
     key = jax.random.PRNGKey(3)
     p = A.init_attention(cfg, L.ArrayMaker(key))
@@ -168,9 +168,9 @@ def test_attn_decode_paged_int8_pallas_matches_jnp(monkeypatch):
                           jnp.float32)
     bt = jnp.asarray([[0, 2, 9], [5, 1, 3]], jnp.int32)   # incl. OOB pad
     pos = jnp.asarray([6, 11], jnp.int32)
-    monkeypatch.delenv("REPRO_PAGED_ATTN", raising=False)
+    monkeypatch.setattr(A, "_paged_kernel", lambda: False)
     out_jnp, pool_jnp = A.attn_decode_paged(p, cfg, x, pool, bt, pos)
-    monkeypatch.setenv("REPRO_PAGED_ATTN", "pallas")
+    monkeypatch.setattr(A, "_paged_kernel", lambda: True)
     out_pl, pool_pl = A.attn_decode_paged(p, cfg, x, pool, bt, pos)
     np.testing.assert_allclose(np.asarray(out_pl), np.asarray(out_jnp),
                                rtol=3e-5, atol=3e-5)
