@@ -24,6 +24,7 @@ from repro.data.tokenizer import encode_batch
 from repro.models import frontends as F
 from repro.models import layers as L
 from repro.models import unet as U
+from repro.profiling import span
 
 TEXT_VOCAB = 4096
 
@@ -95,8 +96,11 @@ class SDPipeline:
         """Returns a jitted (cond_emb, uncond_emb, x0, rng) -> latents fn —
         the measured object for the Table-1 latency benchmark. The UNet
         weights enter the compiled program as an argument: closed over,
-        jit would fold them into it as constants."""
+        jit would fold them into it as constants. Each call's dispatch is
+        the host span ``sd.generate``; ``fn.lower(cond, uncond, x0, rng)``
+        lowers the program."""
         sched = self.sched
+        unet_params = self.params["unet"]
 
         @jax.jit
         def run(unet_params, cond, uncond, x0, rng):
@@ -104,7 +108,12 @@ class SDPipeline:
                           uncond, stepper=stepper, eta=eta, rng=rng,
                           **combine_kw)
 
-        return functools.partial(run, self.params["unet"])
+        def generate(cond, uncond, x0, rng):
+            with span("sd.generate"):
+                return run(unet_params, cond, uncond, x0, rng)
+
+        generate.lower = functools.partial(run.lower, unet_params)
+        return generate
 
     def timed_generate(self, prompts, plan: GuidancePlan, *, seed=0,
                        warmup: int = 2, iters: int = 5):

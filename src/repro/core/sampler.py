@@ -16,6 +16,11 @@ plan, arxiv 2404.07724) while the pass schedule stays the plan's.
 
 Steppers: DDIM (eta=0, the paper's 50-step setting), Euler
 (probability-flow ODE) and ancestral DDPM.
+
+Each segment's scan body runs under the named scope ``sd.step.full`` or
+``sd.step.cond``, with the combine under ``sd.combine`` and the stepper
+update under ``sd.update``; the profiler's trace keeps these names on the
+device ops (DESIGN.md §13).
 """
 
 from __future__ import annotations
@@ -32,6 +37,15 @@ from repro.core.schedules import NoiseSchedule
 from repro.core.selective import GuidancePlan, Mode, round_half_up
 
 COMBINE_MODES = ("cfg", "apg", "interval")
+STEP_SCOPES = {Mode.FULL: "sd.step.full", Mode.COND: "sd.step.cond"}
+
+
+def _scoped(mode: Mode, body):
+    """``body`` run under its segment's named scope."""
+    def run(carry, i):
+        with jax.named_scope(STEP_SCOPES[mode]):
+            return body(carry, i)
+    return run
 
 
 def _segment_scale(plan: GuidancePlan, combine: str,
@@ -122,20 +136,22 @@ def sample(
     step_scale = _segment_scale(plan, combine, interval)
 
     def update(x, eps, i, key):
-        noise = jax.random.normal(key, x.shape, jnp.float32) if stochastic else None
-        if stepper == "ddim":
-            return ddim_update(x, eps, ab_t[i], ab_prev[i], eta=eta, noise=noise)
-        if stepper == "euler":
-            return euler_update(x, eps, ab_t[i], ab_prev[i])
-        if stepper == "ddpm":
-            return ddpm_update(x, eps, ab_t[i], ab_prev[i], noise)
-        raise ValueError(stepper)
+        with jax.named_scope("sd.update"):
+            noise = jax.random.normal(key, x.shape, jnp.float32) if stochastic else None
+            if stepper == "ddim":
+                return ddim_update(x, eps, ab_t[i], ab_prev[i], eta=eta, noise=noise)
+            if stepper == "euler":
+                return euler_update(x, eps, ab_t[i], ab_prev[i])
+            if stepper == "ddpm":
+                return ddpm_update(x, eps, ab_t[i], ab_prev[i], noise)
+            raise ValueError(stepper)
 
     def combine_eps(e_u, e_c, i, diff=None):
-        if combine == "apg":
-            return apg_combine(e_u, e_c, step_scale(i), eta=apg_eta,
-                               threshold=apg_threshold, diff=diff)
-        return cfg_combine(e_u, e_c, step_scale(i))
+        with jax.named_scope("sd.combine"):
+            if combine == "apg":
+                return apg_combine(e_u, e_c, step_scale(i), eta=apg_eta,
+                                   threshold=apg_threshold, diff=diff)
+            return cfg_combine(e_u, e_c, step_scale(i))
 
     def full_step(x, i):
         t2 = jnp.broadcast_to(ts[i], (2 * B,))
@@ -171,14 +187,15 @@ def sample(
         carry = (x_init, jnp.zeros(x_init.shape, jnp.float32))
         for seg in plan.segments:
             body = full_step_m if seg.mode is Mode.FULL else cond_step_m
-            carry, _ = jax.lax.scan(body, carry,
+            carry, _ = jax.lax.scan(_scoped(seg.mode, body), carry,
                                     jnp.arange(seg.start, seg.stop))
         return carry[0]
 
     x = x_init
     for seg in plan.segments:
         body = full_step if seg.mode is Mode.FULL else cond_step
-        x, _ = jax.lax.scan(body, x, jnp.arange(seg.start, seg.stop))
+        x, _ = jax.lax.scan(_scoped(seg.mode, body), x,
+                            jnp.arange(seg.start, seg.stop))
     return x
 
 

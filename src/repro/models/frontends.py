@@ -32,8 +32,10 @@ def init_text_encoder(cfg: ModelConfig, mk):
 
 
 def encode_text(params, cfg: ModelConfig, tokens):
-    """tokens (B,L) int32 -> (B,L,d_model)."""
-    h, _, _ = T.forward(params, cfg, tokens)
+    """tokens (B,L) int32 -> (B,L,d_model), under the named scope
+    ``sd.encode``."""
+    with jax.named_scope("sd.encode"):
+        h, _, _ = T.forward(params, cfg, tokens)
     return h
 
 

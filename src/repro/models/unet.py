@@ -5,6 +5,12 @@ injection, GroupNorm+SiLU, self-attention + cross-attention (to text
 embeddings) at configured resolutions, down/up sampling with skip
 connections. Scaled by ``UNetConfig`` so the full guided pipeline runs on
 CPU for the paper-claim validation (Table 1 / Figs 1-4).
+
+``unet_forward`` runs under the named scope ``unet``: ``unet.time`` (time
+MLP), ``unet.io`` (``conv_in``, ``gn_out``, ``conv_out``), one scope per
+level (``unet.down.<lvl>``, ``unet.mid``, ``unet.up.<lvl>``) and inside
+them ``unet.res``, ``unet.attn.norm``, ``unet.attn.self``,
+``unet.attn.cross`` and ``unet.resample`` (DESIGN.md §13).
 """
 
 from __future__ import annotations
@@ -63,15 +69,16 @@ def init_resblock(mk, cin, cout, time_dim):
 
 
 def resblock(p, x, t_emb, groups):
-    h = jax.nn.silu(groupnorm(p["gn1"], x, groups).astype(jnp.float32)).astype(x.dtype)
-    h = conv2d(p["conv1"], h)
-    t = jax.nn.silu(t_emb.astype(jnp.float32)).astype(x.dtype)
-    t = t @ p["time_proj"]["w"].astype(x.dtype) + p["time_proj"]["b"].astype(x.dtype)
-    h = h + t[:, None, None, :]
-    h = jax.nn.silu(groupnorm(p["gn2"], h, groups).astype(jnp.float32)).astype(x.dtype)
-    h = conv2d(p["conv2"], h)
-    skip = conv2d(p["skip"], x) if "skip" in p else x
-    return skip + h
+    with jax.named_scope("unet.res"):
+        h = jax.nn.silu(groupnorm(p["gn1"], x, groups).astype(jnp.float32)).astype(x.dtype)
+        h = conv2d(p["conv1"], h)
+        t = jax.nn.silu(t_emb.astype(jnp.float32)).astype(x.dtype)
+        t = t @ p["time_proj"]["w"].astype(x.dtype) + p["time_proj"]["b"].astype(x.dtype)
+        h = h + t[:, None, None, :]
+        h = jax.nn.silu(groupnorm(p["gn2"], h, groups).astype(jnp.float32)).astype(x.dtype)
+        h = conv2d(p["conv2"], h)
+        skip = conv2d(p["skip"], x) if "skip" in p else x
+        return skip + h
 
 
 def init_attnblock(mk, c, heads, text_dim):
@@ -103,9 +110,12 @@ def _mha(p, q_in, kv_in, heads):
 
 def attnblock(p, x, text, heads, groups):
     B, H, W, C = x.shape
-    h = groupnorm(p["gn"], x, groups).reshape(B, H * W, C)
-    h = h + _mha(p["self"], h, h, heads)
-    h = h + _mha(p["cross"], h, text, heads)
+    with jax.named_scope("unet.attn.norm"):
+        h = groupnorm(p["gn"], x, groups).reshape(B, H * W, C)
+    with jax.named_scope("unet.attn.self"):
+        h = h + _mha(p["self"], h, h, heads)
+    with jax.named_scope("unet.attn.cross"):
+        h = h + _mha(p["cross"], h, text, heads)
     return x + h.reshape(B, H, W, C)
 
 
@@ -157,37 +167,50 @@ def init_unet(cfg, mk):
 
 def unet_forward(params, cfg, x, t, text):
     """x (B,h,w,Cin) latents, t (B,) timesteps, text (B,L,text_dim)."""
-    g = cfg.norm_groups
-    te = L.sinusoidal_embedding(t, cfg.base_channels)
-    tm = params["time_mlp"]
-    te = jax.nn.silu(te @ tm["w1"].astype(te.dtype) + tm["b1"].astype(te.dtype))
-    te = te @ tm["w2"].astype(te.dtype) + tm["b2"].astype(te.dtype)
+    with jax.named_scope("unet"):
+        return _unet_forward(params, cfg, x, t, text)
 
-    h = conv2d(params["conv_in"], x)
+
+def _unet_forward(params, cfg, x, t, text):
+    g = cfg.norm_groups
+    with jax.named_scope("unet.time"):
+        te = L.sinusoidal_embedding(t, cfg.base_channels)
+        tm = params["time_mlp"]
+        te = jax.nn.silu(te @ tm["w1"].astype(te.dtype) + tm["b1"].astype(te.dtype))
+        te = te @ tm["w2"].astype(te.dtype) + tm["b2"].astype(te.dtype)
+
+    with jax.named_scope("unet.io"):
+        h = conv2d(params["conv_in"], x)
     skips = [h]
     n_lvls = len(cfg.channel_mults)
     for lvl, lp in enumerate(params["down"]):
-        for rp, ap in zip(lp["res"], lp["attn"]):
-            h = resblock(rp, h, te, g)
-            if ap is not None:
-                h = attnblock(ap, h, text, cfg.num_heads, g)
-            skips.append(h)
-        if lvl < n_lvls - 1:
-            h = conv2d(lp["downsample"], h, stride=2)
-            skips.append(h)
-    h = resblock(params["mid1"], h, te, g)
-    h = attnblock(params["mid_attn"], h, text, cfg.num_heads, g)
-    h = resblock(params["mid2"], h, te, g)
+        with jax.named_scope(f"unet.down.{lvl}"):
+            for rp, ap in zip(lp["res"], lp["attn"]):
+                h = resblock(rp, h, te, g)
+                if ap is not None:
+                    h = attnblock(ap, h, text, cfg.num_heads, g)
+                skips.append(h)
+            if lvl < n_lvls - 1:
+                with jax.named_scope("unet.resample"):
+                    h = conv2d(lp["downsample"], h, stride=2)
+                skips.append(h)
+    with jax.named_scope("unet.mid"):
+        h = resblock(params["mid1"], h, te, g)
+        h = attnblock(params["mid_attn"], h, text, cfg.num_heads, g)
+        h = resblock(params["mid2"], h, te, g)
     for i, lp in enumerate(params["up"]):
         lvl = n_lvls - 1 - i
-        for rp, ap in zip(lp["res"], lp["attn"]):
-            h = jnp.concatenate([h, skips.pop()], axis=-1)
-            h = resblock(rp, h, te, g)
-            if ap is not None:
-                h = attnblock(ap, h, text, cfg.num_heads, g)
-        if lvl > 0:
-            B, hh, ww, c = h.shape
-            h = jax.image.resize(h, (B, hh * 2, ww * 2, c), "nearest")
-            h = conv2d(lp["upsample"], h)
-    h = jax.nn.silu(groupnorm(params["gn_out"], h, g).astype(jnp.float32)).astype(h.dtype)
-    return conv2d(params["conv_out"], h)
+        with jax.named_scope(f"unet.up.{lvl}"):
+            for rp, ap in zip(lp["res"], lp["attn"]):
+                h = jnp.concatenate([h, skips.pop()], axis=-1)
+                h = resblock(rp, h, te, g)
+                if ap is not None:
+                    h = attnblock(ap, h, text, cfg.num_heads, g)
+            if lvl > 0:
+                with jax.named_scope("unet.resample"):
+                    B, hh, ww, c = h.shape
+                    h = jax.image.resize(h, (B, hh * 2, ww * 2, c), "nearest")
+                    h = conv2d(lp["upsample"], h)
+    with jax.named_scope("unet.io"):
+        h = jax.nn.silu(groupnorm(params["gn_out"], h, g).astype(jnp.float32)).astype(h.dtype)
+        return conv2d(params["conv_out"], h)

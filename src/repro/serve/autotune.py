@@ -124,6 +124,8 @@ class BudgetAutotuner:
             return None
         raw = int(self.target_tick_s / per_pass) if per_pass > 0 else \
             (self.max_budget or self.min_budget)
+        if per_pass > 0 and raw * per_pass > self.target_tick_s:
+            raw -= 1  # the float quotient rounded up past the envelope
         if self.max_budget is not None:
             raw = min(raw, self.max_budget)
         return max(self.min_budget, raw)

@@ -8,26 +8,23 @@ recorded as a :class:`TickTiming`. The Chrome-trace export renders these
 as nested spans inside each tick, and their sum accounts for the tick's
 wall time within bookkeeping overhead (asserted by the ``obs`` suite).
 
-With ``REPRO_PROFILE=1`` the same structure is mirrored into the JAX
-profiler: the tick becomes a ``StepTraceAnnotation`` and each segment a
-``TraceAnnotation``, so an ``xprof``/TensorBoard capture lines host-side
-phases up against device activity. The env var is read at call time (not
-import time) and the default path stays annotation-free.
+The same structure is mirrored into the JAX profiler's trace through
+:func:`repro.profiling.span`: the tick is a step span ``serve_tick`` and
+each segment a span ``serve.<segment>``, so an ``xprof``/TensorBoard
+capture lines host-side phases up against device activity. The spans
+record only while a profiler runs.
 """
 
 from __future__ import annotations
 
-import os
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass
 
+from repro.profiling import span
+
 #: Canonical segment order within one engine tick.
 TICK_SEGMENTS = ("admit", "schedule", "step", "finalize")
-
-
-def profiling_enabled() -> bool:
-    return os.environ.get("REPRO_PROFILE") == "1"
 
 
 @dataclass(frozen=True)
@@ -77,30 +74,22 @@ class TickTimer:
     def __init__(self, tick: int):
         self.tick = tick
         self._segments: list[tuple[str, float, float]] = []
-        self._step_ann = None
-        if profiling_enabled():  # pragma: no cover - needs profiler run
-            import jax
-            self._step_ann = jax.profiler.StepTraceAnnotation(
-                "serve_tick", step_num=tick)
-            self._step_ann.__enter__()
+        self._step_ann = span("serve_tick", step=tick)
+        self._step_ann.__enter__()
         self.t0 = time.perf_counter()
 
     @contextmanager
     def phase(self, name: str):
         start = time.perf_counter()
         try:
-            if profiling_enabled():  # pragma: no cover - needs profiler
-                import jax
-                with jax.profiler.TraceAnnotation(f"serve.{name}"):
-                    yield
-            else:
+            with span(f"serve.{name}"):
                 yield
         finally:
             self._segments.append((name, start, time.perf_counter()))
 
     def finish(self) -> TickTiming:
         t1 = time.perf_counter()
-        if self._step_ann is not None:  # pragma: no cover - profiler run
+        if self._step_ann is not None:
             self._step_ann.__exit__(None, None, None)
             self._step_ann = None
         return TickTiming(self.tick, self.t0, t1, tuple(self._segments))

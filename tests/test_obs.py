@@ -36,7 +36,7 @@ from repro.serve import (BudgetAutotuner, ContinuousEngine, Log2Histogram,
                          fold_counters, simulate, to_chrome_trace,
                          write_chrome_trace)
 from repro.serve.obs import EVENT_KINDS, FOLDED_COUNTERS, EventTrace
-from repro.serve.obs.timing import TickTimer, profiling_enabled
+from repro.serve.obs.timing import TickTimer
 
 pytestmark = pytest.mark.obs
 
@@ -264,13 +264,40 @@ def test_tick_timer_segments_bracketed():
         assert timing.t0 <= start <= end <= timing.t1
 
 
-def test_profiling_env_gate(monkeypatch):
-    monkeypatch.delenv("REPRO_PROFILE", raising=False)
-    assert not profiling_enabled()
-    monkeypatch.setenv("REPRO_PROFILE", "0")
-    assert not profiling_enabled()
-    monkeypatch.setenv("REPRO_PROFILE", "1")
-    assert profiling_enabled()
+def _one_tick(tick):
+    timer = TickTimer(tick)
+    for name in ("admit", "step"):
+        with timer.phase(name):
+            sum(range(1000))
+    return timer.finish()
+
+
+def _host_event_names(trace_dir):
+    from jax.profiler import ProfileData
+    [path] = list(trace_dir.rglob("*.xplane.pb"))
+    return {ev.name for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def test_tick_spans_in_profiler_trace(tmp_path):
+    """While a profiler runs, a tick is a step span ``serve_tick`` in its
+    trace and each segment a span ``serve.<segment>``."""
+    with jax.profiler.trace(str(tmp_path)):
+        _one_tick(3)
+    names = _host_event_names(tmp_path)
+    assert {"serve_tick", "serve.admit", "serve.step"} <= names
+
+
+def test_tick_segments_same_without_profiler(tmp_path):
+    """The profiler is the only switch: a tick records the same segments
+    with one running and without."""
+    with jax.profiler.trace(str(tmp_path)):
+        traced = _one_tick(4)
+    plain = _one_tick(5)
+    assert [n for n, _, _ in traced.segments] == [n for n, _, _ in plain.segments] \
+        == ["admit", "step"]
+    assert set(plain.segment_s()) == set(traced.segment_s())
 
 
 # ---------------------------------------------------------------------------
