@@ -1,14 +1,23 @@
-"""Flash attention (prefill) — Pallas TPU kernel.
+"""Flash attention — Pallas TPU kernel.
 
 Grid (B, K, nq, nk); the last grid axis is the sequential KV sweep with the
-online-softmax running state (m, l, acc) held in VMEM scratch. GQA is free:
-the K/V BlockSpec index_map sends query-head-group g to kv head g — no
-head-replicated KV ever materialises. Causal + sliding-window masks are
-applied in-kernel; fully-masked tiles still execute (masked) — the TPU grid
-is sequential so correctness is unaffected.
+online-softmax running state (m, l, acc) held in VMEM scratch, so no
+(S, S) score tensor ever reaches HBM. GQA is free: the K/V BlockSpec
+index_map sends query-head-group g to kv head g — no head-replicated KV
+ever materialises. Causal + sliding-window masks are applied in-kernel;
+fully-masked tiles still execute (masked) — the TPU grid is sequential so
+correctness is unaffected. Without either mask the kernel builds no
+positions and no mask at all.
+
+Two callers: causal (and windowed) prefill for the LM attention, and the
+non-causal self-attention of the SD UNet's large levels
+(``models/unet.py``, head dim 40 at 64x64 latents), which passes bf16
+q/k/v with the softmax scale folded into q (``scale=1.0``) and asks for an
+f32 output. Scores, running max, sum and accumulator are f32 throughout;
+p is cast to v's dtype for the PV product, which accumulates in f32.
 
 Block sizes default to (128 q x 128 kv) tiles at hd lanes — MXU-aligned for
-hd in {64, 128, 256}.
+hd in {64, 128, 256}; the UNet picks larger ones for its sequence lengths.
 """
 
 from __future__ import annotations
@@ -39,22 +48,27 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     k = k_ref[...]                                  # (bk, hd)
     v = v_ref[...]
     s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq, 1), 1)
-    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
-    mask = jnp.bool_(True)
-    if causal:
-        mask = kpos <= qpos
-    if window is not None:
-        mask = mask & (kpos > qpos - window)
-    s = jnp.where(mask, s, NEG_INF)                 # (rep, bq, bk)
+                            preferred_element_type=jnp.float32)
+    if scale != 1.0:
+        s = s * scale
+    if causal or window is not None:
+        qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq, 1), 1)
+        kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+        mask = jnp.bool_(True)
+        if causal:
+            mask = kpos <= qpos
+        if window is not None:
+            mask = mask & (kpos > qpos - window)
+        s = jnp.where(mask, s, NEG_INF)             # (rep, bq, bk)
 
+    # m and l are (rep, bq, 1) columns: the row reductions produce them in
+    # that layout and the broadcasts back over the tile read them from it
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[..., None])
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-    acc_ref[...] = (acc_ref[...] * corr[..., None]
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = (acc_ref[...] * corr
                     + jax.lax.dot_general(p.astype(v.dtype), v,
                                           (((2,), (0,)), ((), ())),
                                           preferred_element_type=jnp.float32))
@@ -63,13 +77,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(j == nk - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[...] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: int | None = None, bq: int = 128,
-                           bk: int = 128, interpret: bool = True):
-    """q (B,S,H,hd); k,v (B,S,K,hd). Returns (B,S,H,hd)."""
+                           bk: int = 128, scale: float | None = None,
+                           out_dtype=None, interpret: bool = True):
+    """q (B,S,H,hd); k,v (B,S,K,hd). Returns (B,S,H,hd) in ``out_dtype``
+    (default q's). ``scale`` multiplies the scores (default 1/sqrt(hd));
+    at 1.0 the kernel skips the multiply."""
     B, S, H, hd = q.shape
     K = k.shape[2]
     rep = H // K
@@ -77,7 +94,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
     bk = min(bk, S)
     assert S % bq == 0 and S % bk == 0, (S, bq, bk)
     nq, nk = S // bq, S // bk
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     # layout: q (B,K,rep,S,hd); kv (B,K,S,hd)
     qr = q.reshape(B, S, K, rep, hd).transpose(0, 2, 3, 1, 4)
@@ -95,10 +113,10 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         ],
         out_specs=pl.BlockSpec((None, None, rep, bq, hd),
                                lambda b, g, i, j: (b, g, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, K, rep, S, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, rep, S, hd), out_dtype or q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((rep, bq), jnp.float32),
-            pltpu.VMEM((rep, bq), jnp.float32),
+            pltpu.VMEM((rep, bq, 1), jnp.float32),
+            pltpu.VMEM((rep, bq, 1), jnp.float32),
             pltpu.VMEM((rep, bq, hd), jnp.float32),
         ],
         interpret=interpret,

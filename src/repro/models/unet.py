@@ -11,6 +11,10 @@ MLP), ``unet.io`` (``conv_in``, ``gn_out``, ``conv_out``), one scope per
 level (``unet.down.<lvl>``, ``unet.mid``, ``unet.up.<lvl>``) and inside
 them ``unet.res``, ``unet.attn.norm``, ``unet.attn.self``,
 ``unet.attn.cross`` and ``unet.resample`` (DESIGN.md §13).
+
+Self-attention over ``FLASH_MIN_LEN`` positions or more runs the Pallas
+flash kernel on a TPU (DESIGN.md §6); everything else, cross-attention
+and every CPU run included, takes the einsum path.
 """
 
 from __future__ import annotations
@@ -20,7 +24,15 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attention import flash_attention_pallas
 from repro.models import layers as L
+
+# Self-attention over at least this many positions (32x32 latents and
+# up) takes the flash kernel on a TPU, in (bq, bk) blocks; both from a
+# sweep on a v5e, where 16x16 (256 positions) was faster on einsums
+# (PERF.md §6).
+FLASH_MIN_LEN = 1024
+FLASH_BLOCKS = (1024, 1024)
 
 
 def _conv_init(mk, kh, kw, cin, cout, name_axes=("time", "time", "embed", "mlp")):
@@ -96,16 +108,39 @@ def init_attnblock(mk, c, heads, text_dim):
     }
 
 
-def _mha(p, q_in, kv_in, heads):
+def _flash_blocks(n: int):
+    """The flash kernel's (bq, bk) for self-attention over ``n``
+    positions, or None for the einsum path: the kernel runs on a TPU, for
+    ``n`` of at least ``FLASH_MIN_LEN`` and a multiple of both blocks."""
+    bq, bk = FLASH_BLOCKS
+    if (jax.default_backend() != "tpu" or n < FLASH_MIN_LEN
+            or n % bq or n % bk):
+        return None
+    return FLASH_BLOCKS
+
+
+def _mha(p, q_in, kv_in, heads, blocks=None):
+    """Multi-head attention of ``q_in`` over ``kv_in``; ``blocks`` (bq, bk)
+    runs softmax(QK^T)V through the flash kernel, None through einsums."""
     B, Nq, C = q_in.shape
     hd = C // heads
     q = (q_in @ p["wq"].astype(q_in.dtype)).reshape(B, Nq, heads, hd)
     k = (kv_in @ p["wk"].astype(q_in.dtype)).reshape(B, -1, heads, hd)
     v = (kv_in @ p["wv"].astype(q_in.dtype)).reshape(B, -1, heads, hd)
-    s = jnp.einsum("bqhk,bshk->bhqs", q, k).astype(jnp.float32) / math.sqrt(hd)
-    w = jax.nn.softmax(s, axis=-1).astype(q_in.dtype)
-    o = jnp.einsum("bhqs,bshk->bqhk", w, v).reshape(B, Nq, C)
-    return o @ p["wo"].astype(q_in.dtype)
+    if blocks is None:
+        s = jnp.einsum("bqhk,bshk->bhqs", q, k).astype(jnp.float32) / math.sqrt(hd)
+        w = jax.nn.softmax(s, axis=-1).astype(q_in.dtype)
+        o = jnp.einsum("bhqs,bshk->bqhk", w, v)
+    else:
+        # the default TPU precision feeds the einsums' products bf16 q, k,
+        # v and p; the kernel takes the same, with f32 softmax and output
+        bf = jnp.bfloat16
+        o = flash_attention_pallas(
+            (q * (1.0 / math.sqrt(hd))).astype(bf), k.astype(bf),
+            v.astype(bf), causal=False, bq=blocks[0], bk=blocks[1],
+            scale=1.0, out_dtype=q_in.dtype,
+            interpret=jax.default_backend() != "tpu")
+    return o.reshape(B, Nq, C) @ p["wo"].astype(q_in.dtype)
 
 
 def attnblock(p, x, text, heads, groups):
@@ -113,7 +148,7 @@ def attnblock(p, x, text, heads, groups):
     with jax.named_scope("unet.attn.norm"):
         h = groupnorm(p["gn"], x, groups).reshape(B, H * W, C)
     with jax.named_scope("unet.attn.self"):
-        h = h + _mha(p["self"], h, h, heads)
+        h = h + _mha(p["self"], h, h, heads, _flash_blocks(H * W))
     with jax.named_scope("unet.attn.cross"):
         h = h + _mha(p["cross"], h, text, heads)
     return x + h.reshape(B, H, W, C)

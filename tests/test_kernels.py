@@ -42,20 +42,40 @@ def test_rmsnorm_sweep(rows, dim, dtype):
                                np.asarray(expect, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("S,H,K,hd", [(128, 4, 4, 64), (256, 8, 2, 64),
-                                      (128, 8, 1, 128)])
-@pytest.mark.parametrize("causal,window", [(True, None), (True, 64),
-                                           (False, None)])
-def test_flash_attention_sweep(S, H, K, hd, causal, window):
+_FLASH_SHAPES = [(128, 4, 4, 64), (256, 8, 2, 64), (128, 8, 1, 128)]
+_FLASH_MASKS = [(True, None), (True, 64), (False, None)]
+# the UNet's self-attention class: 8 heads of 40, non-causal, bf16 q/k/v
+# with f32 output, blocks with bq != bk and with bk = S
+_FLASH_UNET = [(256, 64, 128), (256, 128, 256), (512, 256, 128),
+               (512, 128, 512)]
+_FLASH_CASES = (
+    [pytest.param(S, H, K, hd, causal, window, jnp.float32, 64, 64,
+                  id=f"{causal}-{window}-{S}-{H}-{K}-{hd}")
+     for causal, window in _FLASH_MASKS for S, H, K, hd in _FLASH_SHAPES]
+    + [pytest.param(S, 8, 8, 40, False, None, jnp.bfloat16, bq, bk,
+                    id=f"unet-{S}-bq{bq}-bk{bk}")
+       for S, bq, bk in _FLASH_UNET])
+
+
+@pytest.mark.parametrize("S,H,K,hd,causal,window,dtype,bq,bk", _FLASH_CASES)
+def test_flash_attention_sweep(S, H, K, hd, causal, window, dtype, bq, bk):
     B = 2
     rng = jax.random.PRNGKey(S + H * K)
-    q = jax.random.normal(rng, (B, S, H, hd), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(rng, 1), (B, S, K, hd), jnp.float32)
-    v = jax.random.normal(jax.random.fold_in(rng, 2), (B, S, K, hd), jnp.float32)
+    q = jax.random.normal(rng, (B, S, H, hd), jnp.float32).astype(dtype)
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (B, S, K, hd),
+                          jnp.float32).astype(dtype)
+    v = jax.random.normal(jax.random.fold_in(rng, 2), (B, S, K, hd),
+                          jnp.float32).astype(dtype)
     out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 bq=64, bk=64)
-    expect = ref.ref_flash_attention(q, k, v, causal=causal, window=window)
-    np.testing.assert_allclose(out, expect, rtol=3e-5, atol=3e-5)
+                                 bq=bq, bk=bk, out_dtype=jnp.float32)
+    assert out.dtype == jnp.float32
+    # the oracle sees the same (possibly bf16) values in f32 arithmetic;
+    # a bf16 kernel differs by its p rounded to bf16 for the PV product
+    f32 = lambda a: a.astype(jnp.float32)
+    expect = ref.ref_flash_attention(f32(q), f32(k), f32(v), causal=causal,
+                                     window=window)
+    tol = 3e-5 if dtype == jnp.float32 else 4e-3
+    np.testing.assert_allclose(out, expect, rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

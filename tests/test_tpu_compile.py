@@ -5,8 +5,11 @@ VMEM rules; the TPU compiler installed with jax can, for a chip that is
 described rather than attached. Each test lowers one kernel at
 llama3.2-1b widths (H=32, K=8, hd=64, vocab 128256; R=16 ragged rows,
 512 pages) or at the SD-UNet latent shape, compiles it for one v5e
-chip, and checks the kernel survived as a ``tpu_custom_call``. Nothing
-runs, so these say nothing about results or time.
+chip, and checks the kernel survived as a ``tpu_custom_call``. The
+UNet's attention block is compiled whole at SD-1.5's 64x64 and 32x32
+levels, where its self-attention must take the flash kernel and leave
+no score tensor in HBM. Nothing runs, so these say nothing about results
+or time.
 
 The topology is described only inside the module fixture: the TPU
 library admits one loader per process at a time, so describing it while
@@ -27,6 +30,8 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_decode_attention import (
     ragged_paged_decode_attention_int8_pallas,
     ragged_paged_decode_attention_pallas)
+from repro.models import layers as L
+from repro.models import unet as U
 
 R, H, K, HD, PAGES, VOCAB = 16, 32, 8, 64, 512, 128256
 
@@ -106,3 +111,27 @@ def test_flash_attention_compiles(one_chip):
              one_chip, ((1, 2048, H, HD), jnp.bfloat16),
              ((1, 2048, K, HD), jnp.bfloat16),
              ((1, 2048, K, HD), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("hw,c", [(64, 320), (32, 640)])
+def test_unet_attnblock_takes_flash_kernel(one_chip, monkeypatch, hw, c):
+    """SD-1.5's attention block at its 64x64 and 32x32 levels, 8 rows (a
+    batch of 4 with CFG), 8 heads, 77x768 text: the self-attention
+    compiles to the flash kernel and no f32[8,8,N,N] score tensor is
+    left (4.3 GB at 64x64 on the einsum path)."""
+    # the dispatch asks the platform, which is the CPU here; the compile
+    # is for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = hw * hw
+    assert U._flash_blocks(n) == U.FLASH_BLOCKS
+    shapes = jax.eval_shape(lambda: U.init_attnblock(
+        L.ArrayMaker(jax.random.PRNGKey(0)), c, 8, 768))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    x = jax.ShapeDtypeStruct((8, hw, hw, c), jnp.float32, sharding=one_chip)
+    text = jax.ShapeDtypeStruct((8, 77, 768), jnp.float32, sharding=one_chip)
+    fn = jax.jit(lambda p, x, t: U.attnblock(p, x, t, 8, 32))
+    hlo = fn.lower(params, x, text).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert f"f32[8,8,{n},{n}]" not in hlo

@@ -91,3 +91,54 @@ def test_timed_generate_protocol(pipe):
     out, mean_s, std_s = pipe.timed_generate(["x"], plan, warmup=1, iters=2)
     assert out.shape[0] == 1
     assert mean_s > 0
+
+
+def _attn_inputs(hw, c, heads=8, text_dim=64, text_len=16):
+    key = jax.random.PRNGKey(hw)
+    p = U.init_attnblock(L.ArrayMaker(key), c, heads, text_dim)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, hw, hw, c))
+    text = jax.random.normal(jax.random.fold_in(key, 2), (2, text_len, text_dim))
+    return p, x, text
+
+
+def test_self_attention_kernel_matches_einsum():
+    """The flash-kernel form of the self-attention (interpreted here, at
+    the UNet's head dim 40) gives the einsum form's output within bf16
+    rounding: it takes bf16 q/k/v and p where the einsums here are f32."""
+    p, x, _ = _attn_inputs(16, 320)
+    h = x.reshape(2, 256, 320)
+    out = U._mha(p["self"], h, h, 8, blocks=(128, 256))
+    expect = U._mha(p["self"], h, h, 8)
+    assert out.dtype == expect.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=1e-2, atol=1e-2)
+
+
+def test_attnblock_dispatch(monkeypatch):
+    """Off a TPU every attention takes the einsum path. On one, only a
+    self-attention over at least FLASH_MIN_LEN positions takes the
+    kernel; cross-attention (text keys) never does."""
+    assert U._flash_blocks(64 * 64) is None          # CPU: einsum path
+    p, x, text = _attn_inputs(16, 64)
+    expect = U.attnblock(p, x, text, 8, 8)
+    calls = []
+    kernel = U.flash_attention_pallas
+
+    def spy(q, k, v, **kw):
+        calls.append((q.shape[1], k.shape[1]))
+        return kernel(q, k, v, **{**kw, "interpret": True})
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(U, "flash_attention_pallas", spy)
+    monkeypatch.setattr(U, "FLASH_MIN_LEN", 256)
+    monkeypatch.setattr(U, "FLASH_BLOCKS", (128, 128))
+    assert U._flash_blocks(256) == (128, 128)
+    assert U._flash_blocks(192) is None               # below the threshold
+    assert U._flash_blocks(320) is None               # not a block multiple
+    out = U.attnblock(p, x, text, 8, 8)               # 16x16: 256 positions
+    assert calls == [(256, 256)]                      # self only, not cross
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=1e-2, atol=1e-2)
+    small = _attn_inputs(8, 64)
+    U.attnblock(*small, 8, 8)                         # 8x8: 64 positions
+    assert calls == [(256, 256)]
