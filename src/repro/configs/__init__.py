@@ -1,6 +1,7 @@
 from repro.configs.base import (
     InputShape,
     MLAConfig,
+    MMDiTConfig,
     MoEConfig,
     ModelConfig,
     SHAPES,
@@ -12,6 +13,7 @@ __all__ = [
     "ARCHS",
     "InputShape",
     "MLAConfig",
+    "MMDiTConfig",
     "MoEConfig",
     "ModelConfig",
     "SHAPES",

@@ -112,6 +112,7 @@ class UNetConfig:
     latent_size: int = 32
     time_dim: int = 512
     norm_groups: int = 32
+    family = "unet"
     source = "arXiv:2112.10752 (SD), scaled for CPU validation"
 
     def reduced(self) -> "UNetConfig":
@@ -120,6 +121,57 @@ class UNetConfig:
                           attn_resolutions=(2,), num_heads=2, text_dim=64,
                           text_len=16, latent_size=8, time_dim=64,
                           norm_groups=8)
+
+
+@dataclass(frozen=True)
+class MMDiTConfig:
+    """SD3-style multimodal diffusion transformer (arXiv:2403.03206): an
+    image stream of latent patches and a text stream that share one
+    attention per block; the last block's text stream only feeds it.
+
+    Fields follow diffusers' ``SD3Transformer2DModel`` config. The defaults
+    are Stable Diffusion 3 Medium's (``transformer/config.json``); the text
+    context is CLIP-L + bigG (77 tokens, 2048 wide, zero-padded to
+    ``joint_attention_dim``) followed by T5-XXL (``t5_len`` tokens)."""
+
+    name: str = "sd3-medium"
+    sample_size: int = 128              # latent height and width
+    patch_size: int = 2
+    in_channels: int = 16
+    out_channels: int = 16
+    num_layers: int = 24
+    num_attention_heads: int = 24
+    attention_head_dim: int = 64
+    joint_attention_dim: int = 4096     # width of the text context
+    caption_projection_dim: int = 1536
+    pooled_projection_dim: int = 2048
+    pos_embed_max_size: int = 192
+    clip_len: int = 77
+    t5_len: int = 256
+    family = "mmdit"
+    source = "arXiv:2403.03206; stabilityai/stable-diffusion-3-medium-diffusers"
+
+    @property
+    def hidden(self) -> int:
+        return self.num_attention_heads * self.attention_head_dim
+
+    @property
+    def image_tokens(self) -> int:
+        return (self.sample_size // self.patch_size) ** 2
+
+    @property
+    def text_tokens(self) -> int:
+        return self.clip_len + self.t5_len
+
+    def reduced(self) -> "MMDiTConfig":
+        """CPU-test size: 2 blocks (the last context-pre-only), 2 heads of
+        16, an 8x8 latent (16 image tokens) and 3 + 4 text tokens."""
+        return MMDiTConfig(name="sd3-smoke", sample_size=8, in_channels=4,
+                           out_channels=4, num_layers=2,
+                           num_attention_heads=2, attention_head_dim=16,
+                           joint_attention_dim=48, caption_projection_dim=32,
+                           pooled_projection_dim=24, pos_embed_max_size=12,
+                           clip_len=3, t5_len=4)
 
 
 @dataclass(frozen=True)

@@ -11,6 +11,7 @@ from repro.configs import (
     mixtral_8x7b,
     qwen3_14b,
     recurrentgemma_9b,
+    sd3_medium,
     sd_unet,
     xlstm_350m,
     yi_9b,
@@ -29,7 +30,9 @@ ARCHS = {
     "h2o-danube-3-4b": h2o_danube3_4b.CONFIG,
 }
 
+# the guided diffusion pipeline's denoisers (``core/pipeline.py``)
 SD_UNET = sd_unet.CONFIG
+SD3_MEDIUM = sd3_medium.CONFIG
 
 
 def list_archs() -> list[str]:

@@ -14,6 +14,7 @@ op; this jnp form is its oracle and the XLA fallback.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -36,7 +37,6 @@ def cfg_combine(eps_uncond, eps_cond, scale):
 
 
 def _use_pallas() -> bool:
-    import jax
     return jax.default_backend() == "tpu"
 
 
@@ -65,13 +65,17 @@ def split_cond_uncond(batched):
     """Inverse of the 2x-batch trick: (2B, ...) -> ((B,...) cond, (B,...) uncond).
 
     Convention everywhere in this framework: conditional first half,
-    unconditional second half.
+    unconditional second half. ``batched`` may be a pytree of arrays (a
+    conditioning of several tensors): each leaf is split.
     """
-    b2 = batched.shape[0]
-    assert b2 % 2 == 0, b2
-    b = b2 // 2
-    return batched[:b], batched[b:]
+    def b(a):
+        assert a.shape[0] % 2 == 0, a.shape[0]
+        return a.shape[0] // 2
+
+    return (jax.tree.map(lambda a: a[:b(a)], batched),
+            jax.tree.map(lambda a: a[b(a):], batched))
 
 
 def merge_cond_uncond(cond, uncond):
-    return jnp.concatenate([cond, uncond], axis=0)
+    """(B, ...) cond and uncond -> (2B, ...), leaf by leaf for pytrees."""
+    return jax.tree.map(lambda c, u: jnp.concatenate([c, u], axis=0), cond, uncond)

@@ -4,6 +4,14 @@ Bundles: hash tokenizer -> small text encoder -> latent UNet denoiser ->
 DDIM sampler with a :class:`GuidancePlan`. Mirrors the HuggingFace pipeline
 the paper instruments, with the selective-guidance optimization as a
 first-class argument.
+
+The denoiser follows the config's family: a ``UNetConfig`` runs
+``models/unet.py``, an ``MMDiTConfig`` SD3's ``models/mmdit.py`` (a
+velocity, stepped by ``stepper="flow"`` over a ``FlowSchedule``), its
+weights under ``params["unet"]`` / ``params["mmdit"]``. The prompt
+helpers (``init``'s text encoder, ``encode_prompts``, ``generate``) are
+the UNet's; SD3's conditioning, a (context, pooled) pair, comes from
+``frontends.encode_sd3`` and goes to ``generate_jit``'s function.
 """
 
 from __future__ import annotations
@@ -16,24 +24,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import UNetConfig
+from repro.configs.base import MMDiTConfig, UNetConfig
 from repro.core.sampler import sample
-from repro.core.schedules import NoiseSchedule
+from repro.core.schedules import FlowSchedule, NoiseSchedule
 from repro.core.selective import GuidancePlan
 from repro.data.tokenizer import encode_batch
 from repro.models import frontends as F
 from repro.models import layers as L
+from repro.models import mmdit as M
 from repro.models import unet as U
 from repro.profiling import span
 
 TEXT_VOCAB = 4096
+#: config family -> forward(params, cfg, latents, t, cond)
+DENOISERS = {"unet": U.unet_forward, "mmdit": M.mmdit_forward}
 
 
 @dataclass
 class SDPipeline:
-    cfg: UNetConfig
+    cfg: UNetConfig | MMDiTConfig
     params: dict
-    sched: NoiseSchedule
+    sched: NoiseSchedule | FlowSchedule
 
     @classmethod
     def init(cls, cfg: UNetConfig, rng, *, dtype=jnp.float32,
@@ -49,6 +60,8 @@ class SDPipeline:
     # -- pieces -------------------------------------------------------------
 
     def text_cfg(self):
+        if self.cfg.family != "unet":
+            raise ValueError("SD3's conditioning comes from frontends.encode_sd3")
         return F.text_encoder_config(TEXT_VOCAB, self.cfg.text_dim, self.cfg.text_len)
 
     def encode_prompts(self, prompts: list[str]):
@@ -59,15 +72,17 @@ class SDPipeline:
         toks = F.null_tokens(batch, self.cfg.text_len)
         return F.encode_text(self.params["text"], self.text_cfg(), toks)
 
-    def eps_fn(self, unet_params=None):
-        """The denoiser ``(latents, t, text) -> eps`` over ``unet_params``
-        (default: this pipeline's weights)."""
+    def eps_fn(self, denoiser_params=None):
+        """The denoiser ``(latents, t, cond) -> eps`` (a velocity for the
+        MMDiT) over ``denoiser_params`` (default: this pipeline's
+        weights)."""
         cfg = self.cfg
-        if unet_params is None:
-            unet_params = self.params["unet"]
+        forward = DENOISERS[cfg.family]
+        if denoiser_params is None:
+            denoiser_params = self.params[cfg.family]
 
-        def fn(latents, t, text):
-            return U.unet_forward(unet_params, cfg, latents, t, text)
+        def fn(latents, t, cond):
+            return forward(denoiser_params, cfg, latents, t, cond)
 
         return fn
 
@@ -94,25 +109,25 @@ class SDPipeline:
     def generate_jit(self, plan: GuidancePlan, *, stepper="ddim", eta=0.0,
                      **combine_kw):
         """Returns a jitted (cond_emb, uncond_emb, x0, rng) -> latents fn —
-        the measured object for the Table-1 latency benchmark. The UNet
-        weights enter the compiled program as an argument: closed over,
-        jit would fold them into it as constants. Each call's dispatch is
-        the host span ``sd.generate``; ``fn.lower(cond, uncond, x0, rng)``
-        lowers the program."""
+        the measured object for the Table-1 latency benchmark. The
+        denoiser's weights enter the compiled program as an argument:
+        closed over, jit would fold them into it as constants. Each call's
+        dispatch is the host span ``sd.generate``; ``fn.lower(cond,
+        uncond, x0, rng)`` lowers the program."""
         sched = self.sched
-        unet_params = self.params["unet"]
+        weights = self.params[self.cfg.family]
 
         @jax.jit
-        def run(unet_params, cond, uncond, x0, rng):
-            return sample(self.eps_fn(unet_params), plan, sched, x0, cond,
+        def run(weights, cond, uncond, x0, rng):
+            return sample(self.eps_fn(weights), plan, sched, x0, cond,
                           uncond, stepper=stepper, eta=eta, rng=rng,
                           **combine_kw)
 
         def generate(cond, uncond, x0, rng):
             with span("sd.generate"):
-                return run(unet_params, cond, uncond, x0, rng)
+                return run(weights, cond, uncond, x0, rng)
 
-        generate.lower = functools.partial(run.lower, unet_params)
+        generate.lower = functools.partial(run.lower, weights)
         return generate
 
     def timed_generate(self, prompts, plan: GuidancePlan, *, seed=0,

@@ -15,7 +15,10 @@ guidance scale to 1.0 for steps outside ``interval`` (fractions of the
 plan, arxiv 2404.07724) while the pass schedule stays the plan's.
 
 Steppers: DDIM (eta=0, the paper's 50-step setting), Euler
-(probability-flow ODE) and ancestral DDPM.
+(probability-flow ODE) and ancestral DDPM over a ``NoiseSchedule``, and
+``flow``, the rectified-flow Euler step over a ``FlowSchedule`` (SD3: the
+denoiser predicts a velocity). The conditioning may be a pytree (SD3's
+context and pooled vector); it is batched leaf by leaf.
 
 Each segment's scan body runs under the named scope ``sd.step.full`` or
 ``sd.step.cond``, with the combine under ``sd.combine`` and the stepper
@@ -33,10 +36,11 @@ import numpy as np
 
 from repro.core.guidance import (apg_combine, cfg_combine, merge_cond_uncond,
                                  split_cond_uncond)
-from repro.core.schedules import NoiseSchedule
+from repro.core.schedules import FlowSchedule, NoiseSchedule
 from repro.core.selective import GuidancePlan, Mode, round_half_up
 
 COMBINE_MODES = ("cfg", "apg", "interval")
+STEPPERS = ("ddim", "euler", "ddpm", "flow")
 STEP_SCOPES = {Mode.FULL: "sd.step.full", Mode.COND: "sd.step.cond"}
 
 
@@ -61,13 +65,51 @@ def _segment_scale(plan: GuidancePlan, combine: str,
     return lambda i: jnp.where((i >= a) & (i < b), s, 1.0)
 
 
-def _step_coeffs(sched: NoiseSchedule, num_steps: int):
+def _step_coeffs(sched, num_steps: int):
+    """Per-step (timestep, a, b): (t, alpha_bar_t, alpha_bar_prev) over a
+    ``NoiseSchedule``, (1000 sigma, sigma, sigma_next) over a
+    ``FlowSchedule``."""
+    if isinstance(sched, FlowSchedule):
+        sig = sched.sigmas(num_steps)
+        return (jnp.asarray(sched.T * sig[:-1], jnp.float32),
+                jnp.asarray(sig[:-1], jnp.float32),
+                jnp.asarray(sig[1:], jnp.float32))
     ts = sched.spaced_timesteps(num_steps)                     # descending
     ab = sched.alphas_bar
     ab_t = ab[ts]
     ab_prev = np.concatenate([ab[ts[1:]], [1.0]])
     return (jnp.asarray(ts, jnp.int32), jnp.asarray(ab_t, jnp.float32),
             jnp.asarray(ab_prev, jnp.float32))
+
+
+def _stepper(sched, num_steps: int, stepper: str, eta: float, rng):
+    """-> (timesteps, update(x, eps, i)): the one stepper dispatch of
+    ``sample`` and ``sample_trajectory``. The update runs under the named
+    scope ``sd.update``; a stochastic one draws its noise from
+    ``fold_in(rng, i)``."""
+    if stepper not in STEPPERS:
+        raise ValueError(f"stepper {stepper!r} not in {STEPPERS}")
+    if (stepper == "flow") != isinstance(sched, FlowSchedule):
+        raise ValueError(f"stepper {stepper!r} does not step a "
+                         f"{type(sched).__name__}")
+    ts, a, b = _step_coeffs(sched, num_steps)
+    stochastic = stepper == "ddpm" or (stepper == "ddim" and eta > 0.0)
+    if stochastic and rng is None:
+        raise ValueError("ddpm / eta>0 needs rng")
+
+    def update(x, eps, i):
+        with jax.named_scope("sd.update"):
+            noise = (jax.random.normal(jax.random.fold_in(rng, i), x.shape,
+                                       jnp.float32) if stochastic else None)
+            if stepper == "ddim":
+                return ddim_update(x, eps, a[i], b[i], eta=eta, noise=noise)
+            if stepper == "euler":
+                return euler_update(x, eps, a[i], b[i])
+            if stepper == "ddpm":
+                return ddpm_update(x, eps, a[i], b[i], noise)
+            return flow_update(x, eps, a[i], b[i])
+
+    return ts, update
 
 
 def ddim_update(x, eps, ab_t, ab_prev, *, eta: float = 0.0, noise=None):
@@ -105,13 +147,20 @@ def ddpm_update(x, eps, ab_t, ab_prev, noise):
     return (mean + sigma * noise.astype(jnp.float32)).astype(x.dtype)
 
 
+def flow_update(x, v, sigma, sigma_next):
+    """Euler step of the rectified-flow ODE dx/dsigma = v (diffusers'
+    ``FlowMatchEulerDiscreteScheduler.step``), in float32."""
+    xf = x.astype(jnp.float32)
+    return (xf + (sigma_next - sigma) * v.astype(jnp.float32)).astype(x.dtype)
+
+
 def sample(
-    eps_fn: Callable,            # (latents (N,...), t (N,), text (N,L,D)) -> eps
+    eps_fn: Callable,            # (latents (N,...), t (N,), cond (N,...)) -> eps
     plan: GuidancePlan,
-    sched: NoiseSchedule,
+    sched: NoiseSchedule | FlowSchedule,
     x_init,                      # (B, h, w, c) initial noise
-    cond_emb,                    # (B, L, D)
-    uncond_emb,                  # (B, L, D)
+    cond_emb,                    # (B, L, D), or a pytree of (B, ...) leaves
+    uncond_emb,                  # as cond_emb
     *,
     stepper: str = "ddim",
     eta: float = 0.0,
@@ -125,26 +174,10 @@ def sample(
     """Run the guided denoising loop under ``plan``. Returns final latents."""
     if combine not in COMBINE_MODES:
         raise ValueError(f"combine {combine!r} not in {COMBINE_MODES}")
-    T = plan.total_steps
-    ts, ab_t, ab_prev = _step_coeffs(sched, T)
+    ts, update = _stepper(sched, plan.total_steps, stepper, eta, rng)
     B = x_init.shape[0]
-    stochastic = stepper == "ddpm" or (stepper == "ddim" and eta > 0.0)
-    if stochastic and rng is None:
-        raise ValueError("ddpm / eta>0 needs rng")
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
     text2 = merge_cond_uncond(cond_emb, uncond_emb)
     step_scale = _segment_scale(plan, combine, interval)
-
-    def update(x, eps, i, key):
-        with jax.named_scope("sd.update"):
-            noise = jax.random.normal(key, x.shape, jnp.float32) if stochastic else None
-            if stepper == "ddim":
-                return ddim_update(x, eps, ab_t[i], ab_prev[i], eta=eta, noise=noise)
-            if stepper == "euler":
-                return euler_update(x, eps, ab_t[i], ab_prev[i])
-            if stepper == "ddpm":
-                return ddpm_update(x, eps, ab_t[i], ab_prev[i], noise)
-            raise ValueError(stepper)
 
     def combine_eps(e_u, e_c, i, diff=None):
         with jax.named_scope("sd.combine"):
@@ -158,12 +191,12 @@ def sample(
         eps2 = eps_fn(merge_cond_uncond(x, x), t2, text2)
         e_c, e_u = split_cond_uncond(eps2)
         eps = combine_eps(e_u, e_c, i)
-        return update(x, eps, i, jax.random.fold_in(rng, i)), None
+        return update(x, eps, i), None
 
     def cond_step(x, i):
         t1 = jnp.broadcast_to(ts[i], (B,))
         eps = eps_fn(x, t1, cond_emb)
-        return update(x, eps, i, jax.random.fold_in(rng, i)), None
+        return update(x, eps, i), None
 
     if combine == "apg" and apg_momentum != 0.0:
         # the MomentumBuffer EMA rides in the scan carry (one running
@@ -177,7 +210,7 @@ def sample(
             diff = (e_c.astype(jnp.float32) - e_u.astype(jnp.float32))
             avg = diff + apg_momentum * avg
             eps = combine_eps(e_u, e_c, i, diff=avg)
-            return (update(x, eps, i, jax.random.fold_in(rng, i)), avg), None
+            return (update(x, eps, i), avg), None
 
         def cond_step_m(carry, i):
             x, avg = carry
@@ -212,32 +245,20 @@ def sample_trajectory(eps_fn, plan, sched, x_init, cond_emb, uncond_emb, **kw):
 
 def _run_segment(eps_fn, plan, sched, x, cond_emb, uncond_emb, seg, *,
                  stepper="ddim", eta=0.0, rng=None):
-    T = plan.total_steps
-    ts, ab_t, ab_prev = _step_coeffs(sched, T)
+    ts, update = _stepper(sched, plan.total_steps, stepper, eta, rng)
     B = x.shape[0]
-    stochastic = stepper == "ddpm" or (stepper == "ddim" and eta > 0.0)
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
     text2 = merge_cond_uncond(cond_emb, uncond_emb)
     s = plan.guidance_scale
-
-    def update(x, eps, i, key):
-        noise = jax.random.normal(key, x.shape, jnp.float32) if stochastic else None
-        if stepper == "ddim":
-            return ddim_update(x, eps, ab_t[i], ab_prev[i], eta=eta, noise=noise)
-        if stepper == "euler":
-            return euler_update(x, eps, ab_t[i], ab_prev[i])
-        return ddpm_update(x, eps, ab_t[i], ab_prev[i], noise)
 
     def full_step(x, i):
         t2 = jnp.broadcast_to(ts[i], (2 * B,))
         eps2 = eps_fn(merge_cond_uncond(x, x), t2, text2)
         e_c, e_u = split_cond_uncond(eps2)
-        return update(x, cfg_combine(e_u, e_c, s), i, jax.random.fold_in(rng, i)), None
+        return update(x, cfg_combine(e_u, e_c, s), i), None
 
     def cond_step(x, i):
         t1 = jnp.broadcast_to(ts[i], (B,))
-        eps = eps_fn(x, t1, cond_emb)
-        return update(x, eps, i, jax.random.fold_in(rng, i)), None
+        return update(x, eps_fn(x, t1, cond_emb), i), None
 
     body = full_step if seg.mode is Mode.FULL else cond_step
     x, _ = jax.lax.scan(body, x, jnp.arange(seg.start, seg.stop))

@@ -33,3 +33,30 @@ class NoiseSchedule:
         step = self.T // num_steps
         ts = (np.arange(num_steps) * step + step - 1)[::-1]
         return ts.astype(np.int32)
+
+
+class FlowSchedule:
+    """Rectified-flow sigmas with a resolution shift (SD3;
+    arXiv:2403.03206 §5.3.2): x_sigma = (1 - sigma) x_0 + sigma noise, the
+    denoiser predicts the velocity noise - x_0 at timestep 1000 sigma.
+
+    ``sigmas(n)`` is diffusers' ``FlowMatchEulerDiscreteScheduler`` with a
+    static shift: n points spaced evenly from sigma_max to sigma_min of the
+    shifted training grid, shifted again (so the last is
+    shift(shift(1/T)), as diffusers computes it), then 0 appended."""
+
+    def __init__(self, shift: float = 3.0, T: int = 1000):
+        self.shift, self.T = shift, T
+
+    @classmethod
+    def sd3_default(cls):
+        return cls(3.0)
+
+    def _shifted(self, s):
+        return self.shift * s / (1 + (self.shift - 1) * s)
+
+    def sigmas(self, num_steps: int) -> np.ndarray:
+        """(num_steps + 1,) float64, descending from 1 to 0."""
+        train = self._shifted(np.arange(1, self.T + 1, dtype=np.float64)[::-1] / self.T)
+        s = np.linspace(train[0], train[-1], num_steps)
+        return np.concatenate([self._shifted(s), [0.0]])

@@ -48,17 +48,26 @@ _FLASH_MASKS = [(True, None), (True, 64), (False, None)]
 # with f32 output, blocks with bq != bk and with bk = S
 _FLASH_UNET = [(256, 64, 128), (256, 128, 256), (512, 256, 128),
                (512, 128, 512)]
+# the MMDiT's joint attention class: 64-wide heads, non-causal, bf16 q/k/v
+# with f32 output, the sequence padded to a block multiple and only the
+# first kv_len keys real: masked in the last real tile (a few real keys in
+# it, or one), with whole tiles of padding after it at (64, 64)
+_FLASH_KV_LEN = [(256, 200, 128, 128), (256, 100, 64, 64), (512, 333, 128, 256),
+                 (384, 257, 128, 128)]
 _FLASH_CASES = (
-    [pytest.param(S, H, K, hd, causal, window, jnp.float32, 64, 64,
+    [pytest.param(S, H, K, hd, causal, window, jnp.float32, 64, 64, None,
                   id=f"{causal}-{window}-{S}-{H}-{K}-{hd}")
      for causal, window in _FLASH_MASKS for S, H, K, hd in _FLASH_SHAPES]
-    + [pytest.param(S, 8, 8, 40, False, None, jnp.bfloat16, bq, bk,
+    + [pytest.param(S, 8, 8, 40, False, None, jnp.bfloat16, bq, bk, None,
                     id=f"unet-{S}-bq{bq}-bk{bk}")
-       for S, bq, bk in _FLASH_UNET])
+       for S, bq, bk in _FLASH_UNET]
+    + [pytest.param(S, 4, 4, 64, False, None, jnp.bfloat16, bq, bk, n,
+                    id=f"kvlen-{n}of{S}-bq{bq}-bk{bk}")
+       for S, n, bq, bk in _FLASH_KV_LEN])
 
 
-@pytest.mark.parametrize("S,H,K,hd,causal,window,dtype,bq,bk", _FLASH_CASES)
-def test_flash_attention_sweep(S, H, K, hd, causal, window, dtype, bq, bk):
+@pytest.mark.parametrize("S,H,K,hd,causal,window,dtype,bq,bk,kv_len", _FLASH_CASES)
+def test_flash_attention_sweep(S, H, K, hd, causal, window, dtype, bq, bk, kv_len):
     B = 2
     rng = jax.random.PRNGKey(S + H * K)
     q = jax.random.normal(rng, (B, S, H, hd), jnp.float32).astype(dtype)
@@ -67,15 +76,33 @@ def test_flash_attention_sweep(S, H, K, hd, causal, window, dtype, bq, bk):
     v = jax.random.normal(jax.random.fold_in(rng, 2), (B, S, K, hd),
                           jnp.float32).astype(dtype)
     out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 bq=bq, bk=bk, out_dtype=jnp.float32)
+                                 bq=bq, bk=bk, out_dtype=jnp.float32,
+                                 kv_len=kv_len)
     assert out.dtype == jnp.float32
     # the oracle sees the same (possibly bf16) values in f32 arithmetic;
-    # a bf16 kernel differs by its p rounded to bf16 for the PV product
-    f32 = lambda a: a.astype(jnp.float32)
+    # a bf16 kernel differs by its p rounded to bf16 for the PV product.
+    # With kv_len it sees the real tokens alone: the kernel's rows of real
+    # queries must not see the padded keys
+    n = S if kv_len is None else kv_len
+    f32 = lambda a: a[:, :n].astype(jnp.float32)
     expect = ref.ref_flash_attention(f32(q), f32(k), f32(v), causal=causal,
                                      window=window)
     tol = 3e-5 if dtype == jnp.float32 else 4e-3
-    np.testing.assert_allclose(out, expect, rtol=tol, atol=tol)
+    np.testing.assert_allclose(out[:, :n], expect, rtol=tol, atol=tol)
+    assert bool(jnp.isfinite(out).all())
+
+
+def test_flash_attention_kv_len_at_full_length_is_unmasked():
+    """``kv_len`` equal to the sequence length masks nothing: the output is
+    the unmasked call's, bit for bit."""
+    B, S, H, hd = 2, 256, 4, 64
+    rng = jax.random.PRNGKey(3)
+    q, k, v = (jax.random.normal(jax.random.fold_in(rng, i), (B, S, H, hd),
+                                 jnp.float32).astype(jnp.bfloat16) for i in range(3))
+    kw = dict(causal=False, bq=128, bk=64, out_dtype=jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention_pallas(q, k, v, kv_len=S, **kw)),
+        np.asarray(flash_attention_pallas(q, k, v, **kw)))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -8,8 +8,9 @@ llama3.2-1b widths (H=32, K=8, hd=64, vocab 128256; R=16 ragged rows,
 chip, and checks the kernel survived as a ``tpu_custom_call``. The
 UNet's attention block is compiled whole at SD-1.5's 64x64 and 32x32
 levels, where its self-attention must take the flash kernel and leave
-no score tensor in HBM. Nothing runs, so these say nothing about results
-or time.
+no score tensor in HBM; so is SD3's joint attention over 4096 + 333
+tokens, padded for the kernel. Nothing runs, so these say nothing about
+results or time.
 
 The topology is described only inside the module fixture: the TPU
 library admits one loader per process at a time, so describing it while
@@ -31,6 +32,7 @@ from repro.kernels.paged_decode_attention import (
     ragged_paged_decode_attention_int8_pallas,
     ragged_paged_decode_attention_pallas)
 from repro.models import layers as L
+from repro.models import mmdit as M
 from repro.models import unet as U
 
 R, H, K, HD, PAGES, VOCAB = 16, 32, 8, 64, 512, 128256
@@ -135,3 +137,24 @@ def test_unet_attnblock_takes_flash_kernel(one_chip, monkeypatch, hw, c):
     hlo = fn.lower(params, x, text).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert f"f32[8,8,{n},{n}]" not in hlo
+
+
+def test_mmdit_joint_attention_takes_flash_kernel(one_chip, monkeypatch):
+    """SD3-Medium's joint attention at its published widths, 4 rows (a
+    batch of 2 with CFG), 24 heads of 64 over 4096 image and 333 text
+    tokens: it compiles to the flash kernel over the sequence padded to a
+    block multiple, and leaves no f32[4,24,N,N] score tensor, at 4429 or
+    at the padded length (7.5 GB at 4429 on the einsum path)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from repro.configs.registry import SD3_MEDIUM as cfg
+    n, m, d = cfg.image_tokens, cfg.text_tokens, cfg.hidden
+    blocks = M._joint_blocks(n + m)
+    padded = M._padded(n + m, blocks)
+    assert (n + m, blocks, padded) == (4429, (448, 4480), 4480)
+    qx = jax.ShapeDtypeStruct((4, n, 3 * d), jnp.float32, sharding=one_chip)
+    qy = jax.ShapeDtypeStruct((4, m, 3 * d), jnp.float32, sharding=one_chip)
+    fn = jax.jit(lambda a, b: M.joint_attention(a, b, cfg.num_attention_heads))
+    hlo = fn.lower(qx, qy).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    for s in (n + m, padded):
+        assert f"f32[4,24,{s},{s}]" not in hlo and f"bf16[4,24,{s},{s}]" not in hlo
