@@ -145,14 +145,16 @@ def unpatchify(x, p, hw, c):
 def _joint_blocks(n: int):
     """The flash kernel's (bq, bk) for the joint attention over ``n``
     tokens, or None for the einsum path (off a TPU): one kv tile over
-    ``n`` padded to a multiple of 128 lanes, and q tiles of the most rows,
-    up to 512 and a multiple of 8, that divide it: (448, 4480) at SD3's
-    4429 tokens, where a sweep on a v5e found one kv tile 40% faster than
-    square 512 tiles (PERF.md §6)."""
+    ``n`` padded to a multiple of 128 lanes, so that the kernel computes
+    each row's softmax in one pass, and q tiles of the most rows, up to
+    1120 and a multiple of 8, that divide it: (1120, 4480) at SD3's 4429
+    tokens, where a sweep on a v5e found one kv tile 40% faster than
+    square 512 tiles, and the one-pass body at a q tile of 1120 10%
+    faster a call than the online softmax at (448, 4480) (PERF.md §6)."""
     if jax.default_backend() != "tpu":
         return None
     p = -(-n // 128) * 128
-    return max(d for d in range(8, 513, 8) if p % d == 0), p
+    return max(d for d in range(8, 1121, 8) if p % d == 0), p
 
 
 def _padded(n: int, blocks) -> int:

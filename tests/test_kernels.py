@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as FA
 from repro.kernels import ops, ref
 from repro.kernels.cfg_combine import cfg_combine_pallas
 from repro.kernels.decode_attention import decode_attention_pallas
@@ -54,20 +55,38 @@ _FLASH_UNET = [(256, 64, 128), (256, 128, 256), (512, 256, 128),
 # it, or one), with whole tiles of padding after it at (64, 64)
 _FLASH_KV_LEN = [(256, 200, 128, 128), (256, 100, 64, 64), (512, 333, 128, 256),
                  (384, 257, 128, 128)]
+# one kv block over the sequence, so each row's softmax in one pass, bqc
+# rows of the q tile at a time (set through the kernel's score-tile
+# budget; the MMDiT's body): kv_len inside the block; one real key in the
+# last 128-lane group; SD3's share of real keys (333 of 384) under three
+# groups of rows; several q tiles of several groups without padding;
+# groups of 32
+_FLASH_ONE_PASS = [(512, 450, 256, 128), (384, 257, 128, 64),
+                   (384, 333, 384, 128), (512, None, 256, 64),
+                   (256, 200, 128, 32)]
 _FLASH_CASES = (
-    [pytest.param(S, H, K, hd, causal, window, jnp.float32, 64, 64, None,
+    [pytest.param(S, H, K, hd, causal, window, jnp.float32, 64, 64, None, None,
                   id=f"{causal}-{window}-{S}-{H}-{K}-{hd}")
      for causal, window in _FLASH_MASKS for S, H, K, hd in _FLASH_SHAPES]
-    + [pytest.param(S, 8, 8, 40, False, None, jnp.bfloat16, bq, bk, None,
+    + [pytest.param(S, 8, 8, 40, False, None, jnp.bfloat16, bq, bk, None, None,
                     id=f"unet-{S}-bq{bq}-bk{bk}")
        for S, bq, bk in _FLASH_UNET]
-    + [pytest.param(S, 4, 4, 64, False, None, jnp.bfloat16, bq, bk, n,
+    + [pytest.param(S, 4, 4, 64, False, None, jnp.bfloat16, bq, bk, n, None,
                     id=f"kvlen-{n}of{S}-bq{bq}-bk{bk}")
-       for S, n, bq, bk in _FLASH_KV_LEN])
+       for S, n, bq, bk in _FLASH_KV_LEN]
+    + [pytest.param(S, 4, 4, 64, False, None, dtype, bq, S, n, bqc,
+                    id=f"onepass-{n}of{S}-bq{bq}-bqc{bqc}-{jnp.dtype(dtype).name}")
+       for S, n, bq, bqc in _FLASH_ONE_PASS
+       for dtype in (jnp.float32, jnp.bfloat16)])
 
 
-@pytest.mark.parametrize("S,H,K,hd,causal,window,dtype,bq,bk,kv_len", _FLASH_CASES)
-def test_flash_attention_sweep(S, H, K, hd, causal, window, dtype, bq, bk, kv_len):
+@pytest.mark.parametrize("S,H,K,hd,causal,window,dtype,bq,bk,kv_len,bqc",
+                         _FLASH_CASES)
+def test_flash_attention_sweep(S, H, K, hd, causal, window, dtype, bq, bk,
+                               kv_len, bqc, monkeypatch):
+    if bqc is not None:
+        monkeypatch.setattr(FA, "SCORE_TILE_BYTES", 4 * (H // K) * bk * bqc)
+        assert FA._row_group(bq, H // K, bk) == bqc
     B = 2
     rng = jax.random.PRNGKey(S + H * K)
     q = jax.random.normal(rng, (B, S, H, hd), jnp.float32).astype(dtype)
@@ -103,6 +122,27 @@ def test_flash_attention_kv_len_at_full_length_is_unmasked():
     np.testing.assert_array_equal(
         np.asarray(flash_attention_pallas(q, k, v, kv_len=S, **kw)),
         np.asarray(flash_attention_pallas(q, k, v, **kw)))
+
+
+def test_flash_attention_one_pass_is_online_over_one_block(monkeypatch):
+    """Over one kv block the one-pass body is the online body's arithmetic
+    (its rescale multiplies by exact zeros): the same output bit for bit,
+    with and without padded keys, whatever the group of rows."""
+    B, S, H, hd = 2, 256, 4, 64
+    rng = jax.random.PRNGKey(5)
+    q, k, v = (jax.random.normal(jax.random.fold_in(rng, i), (B, S, H, hd),
+                                 jnp.float32).astype(jnp.bfloat16) for i in range(3))
+    for kv_len in (None, 200):
+        kw = dict(causal=False, bq=128, bk=S, out_dtype=jnp.float32,
+                  kv_len=kv_len)
+        with monkeypatch.context() as m:
+            m.setattr(FA, "_one_pass", lambda *a: False)
+            online = np.asarray(flash_attention_pallas(q, k, v, **kw))
+        for rows in (128, 64, 32):
+            monkeypatch.setattr(FA, "SCORE_TILE_BYTES", 4 * S * rows)
+            assert FA._row_group(128, 1, S) == rows
+            np.testing.assert_array_equal(
+                np.asarray(flash_attention_pallas(q, k, v, **kw)), online)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -80,13 +80,14 @@ def test_forward_matches_reference_einsum(weights):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("blocks", [(8, 8), (16, 8), (8, 16)])
+@pytest.mark.parametrize("blocks", [(8, 8), (16, 8), (8, 16), (8, 24), (16, 32)])
 def test_forward_matches_reference_kernel(weights, monkeypatch, blocks):
     """The TPU path's flash kernel (interpreted) over the 23-token joint
     sequence padded to a block multiple (24, or 32 with a kv tile of
-    padding alone at (16, 8)): within bf16 rounding of q, k, v and p
-    (3e-2 on outputs of size ~1, the kernel's own bf16 tolerance scaled
-    through two blocks)."""
+    padding alone at (16, 8)), with the online softmax or, over one kv
+    block, the one-pass body (24 keys in one block; 32 keys, two q
+    tiles): within bf16 rounding of q, k, v and p (3e-2 on outputs of size
+    ~1, the kernel's own bf16 tolerance scaled through two blocks)."""
     monkeypatch.setattr(M, "_joint_blocks", lambda n: blocks)
     calls = []
     kernel = M.flash_attention_pallas
@@ -108,13 +109,13 @@ def test_forward_matches_reference_kernel(weights, monkeypatch, blocks):
 def test_joint_blocks_rule(monkeypatch):
     """Off a TPU the joint attention takes the einsum path. On one: a
     single kv tile over the sequence padded to 128 lanes, q tiles of the
-    largest multiple of 8 up to 512 dividing it."""
+    largest multiple of 8 up to 1120 dividing it."""
     assert M._joint_blocks(4429) is None
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert M._joint_blocks(4429) == (448, 4480)      # SD3 at 1024x1024
-    assert M._padded(4429, (448, 4480)) == 4480
-    assert M._joint_blocks(4608) == (512, 4608)
-    assert M._joint_blocks(1357) == (352, 1408)      # SD3 at 512x512
+    assert M._joint_blocks(4429) == (1120, 4480)     # SD3 at 1024x1024
+    assert M._padded(4429, (1120, 4480)) == 4480
+    assert M._joint_blocks(4608) == (768, 4608)
+    assert M._joint_blocks(1357) == (704, 1408)      # SD3 at 512x512
 
 
 def test_flow_sigmas_shift3():

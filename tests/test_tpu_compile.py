@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import flash_attention as FA
 from repro.kernels.cfg_combine import (apg_combine_pallas, cfg_combine_pallas,
                                        cfg_combine_rowscale_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -119,8 +120,9 @@ def test_flash_attention_compiles(one_chip):
 def test_unet_attnblock_takes_flash_kernel(one_chip, monkeypatch, hw, c):
     """SD-1.5's attention block at its 64x64 and 32x32 levels, 8 rows (a
     batch of 4 with CFG), 8 heads, 77x768 text: the self-attention
-    compiles to the flash kernel and no f32[8,8,N,N] score tensor is
-    left (4.3 GB at 64x64 on the einsum path)."""
+    compiles to the flash kernel (at 32x32 its one kv tile takes the
+    one-pass body, whose VMEM must fit) and no f32[8,8,N,N] score tensor
+    is left (4.3 GB at 64x64 on the einsum path)."""
     # the dispatch asks the platform, which is the CPU here; the compile
     # is for the described chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -143,14 +145,17 @@ def test_mmdit_joint_attention_takes_flash_kernel(one_chip, monkeypatch):
     """SD3-Medium's joint attention at its published widths, 4 rows (a
     batch of 2 with CFG), 24 heads of 64 over 4096 image and 333 text
     tokens: it compiles to the flash kernel over the sequence padded to a
-    block multiple, and leaves no f32[4,24,N,N] score tensor, at 4429 or
-    at the padded length (7.5 GB at 4429 on the einsum path)."""
+    block multiple, one kv block, so each row's softmax in one pass, 224
+    rows at a time (whose VMEM must fit), and leaves no f32[4,24,N,N]
+    score tensor, at 4429 or at the padded length (7.5 GB at 4429 on the
+    einsum path)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     from repro.configs.registry import SD3_MEDIUM as cfg
     n, m, d = cfg.image_tokens, cfg.text_tokens, cfg.hidden
     blocks = M._joint_blocks(n + m)
     padded = M._padded(n + m, blocks)
-    assert (n + m, blocks, padded) == (4429, (448, 4480), 4480)
+    assert (n + m, blocks, padded) == (4429, (1120, 4480), 4480)
+    assert FA._row_group(blocks[0], 1, padded) == 224
     qx = jax.ShapeDtypeStruct((4, n, 3 * d), jnp.float32, sharding=one_chip)
     qy = jax.ShapeDtypeStruct((4, m, 3 * d), jnp.float32, sharding=one_chip)
     fn = jax.jit(lambda a, b: M.joint_attention(a, b, cfg.num_attention_heads))
