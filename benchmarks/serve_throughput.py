@@ -59,10 +59,11 @@ Part 9 (``--policy divergence|interval``): dynamic guidance policies
 ``divergence`` policy drops the uncond stream mid-flight when the EMA'd
 cond/uncond divergence falls below ``--divergence-threshold``, emitting
 ``policy_switch`` events and eliding uncond passes beyond the bound
-plan; ``--combine`` picks the FULL-step combine stage (Eq. 1, APG, or
-interval-gated Eq. 1). The recorded switch steps replayed through the
-offline simulator must reproduce the engine's event stream and the new
-``policy_switches`` / ``uncond_passes_elided_dynamic`` counters exactly.
+plan; ``--combine`` picks the FULL-step combine stage (Eq. 1 or APG),
+at the scale the plan gives each step. The recorded switch steps
+replayed through the offline simulator must reproduce the engine's
+event stream and the new ``policy_switches`` /
+``uncond_passes_elided_dynamic`` counters exactly.
 
 Part 10 (``--replicas N``, N > 1): the fleet tier (DESIGN.md §16) —
 N engine replicas behind the prefix-affinity router vs the seeded
@@ -79,7 +80,7 @@ reproduce every replica's counters and event stream exactly. With
     PYTHONPATH=src python -m benchmarks.serve_throughput [--tiny] \
         [--kv paged] [--reservation lazy] [--kv-dtype int8] \
         [--step auto|ragged|signature] [--trace-out trace.json] \
-        [--policy static|divergence|interval] [--combine cfg|apg|interval] \
+        [--policy static|divergence|interval] [--combine cfg|apg] \
         [--replicas N]
 """
 
@@ -93,6 +94,8 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro.configs import get_smoke_config
+from repro.core.guidance import COMBINE_MODES
+from repro.core.policy import GUIDANCE_POLICIES
 from repro.core.selective import GuidancePlan
 from repro.data.prompts import PAPER_PROMPTS
 from repro.models import layers as L
@@ -539,9 +542,7 @@ def _dynamic_vs_full(params, cfg, *, n_req: int, prompt_len: int,
     switches = {ev.uid: ev.get("step") for ev in m_dyn.trace
                 if ev.kind == "policy_switch"}
     if policy == "interval":
-        from repro.core.policy import IntervalGuidancePolicy
-        plan = IntervalGuidancePolicy(max_new, interval[0], interval[1],
-                                      4.0).bound_plan()
+        plan = GuidancePlan.interval(max_new, interval[0], interval[1], 4.0)
     else:
         plan = GuidancePlan.suffix(max_new, 0.0, 4.0)
     sim_m = simulate([SimRequest(f"y{i}", arrivals[i], plan,
@@ -764,16 +765,15 @@ if __name__ == "__main__":
     ap.add_argument("--only-tier", action="store_true",
                     help="run just the tiered-vs-lazy part (the CI kv-tier "
                          "smoke; needs --host-pool-bytes)")
-    ap.add_argument("--policy", choices=["static", "divergence", "interval"],
+    ap.add_argument("--policy", choices=GUIDANCE_POLICIES,
                     default="static",
                     help="runtime guidance policy (DESIGN.md §15); non-"
                          "static runs the dynamic-vs-FULL comparison with "
                          "engine==sim replay of the recorded switches")
-    ap.add_argument("--combine", choices=["cfg", "apg", "interval"],
+    ap.add_argument("--combine", choices=COMBINE_MODES,
                     default="cfg",
-                    help="FULL-step combine stage: Eq. 1, APG normalized "
-                         "guidance (arxiv 2410.02416), or interval-gated "
-                         "Eq. 1 (arxiv 2404.07724)")
+                    help="FULL-step combine stage: Eq. 1, or APG normalized "
+                         "guidance (arxiv 2410.02416)")
     ap.add_argument("--divergence-threshold", type=float, default=1e9,
                     help="EMA cond/uncond divergence level below which the "
                          "divergence policy drops the uncond stream (the "

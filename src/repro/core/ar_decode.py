@@ -24,8 +24,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.guidance import apg_combine, cfg_combine
-from repro.core.selective import GuidancePlan, Mode, round_half_up
+from repro.core.guidance import COMBINE_MODES, combine_guidance
+from repro.core.selective import GuidancePlan, Mode
 from repro.models import transformer as T
 
 
@@ -49,12 +49,12 @@ def null_prompt(tokens):
 
 
 def decode_step_full(params, cfg, token, caches_c, caches_u, pos, scale,
-                     *, rules=None, long_ctx=False, combine_fn=None):
-    """Baseline CFG decode step: two forwards + Eq. 1.
+                     *, rules=None, long_ctx=False, combine: str = "cfg",
+                     apg_eta: float = 0.0, apg_threshold: float = 0.0):
+    """Baseline CFG decode step: two forwards + the combine stage (Eq. 1,
+    or APG under ``combine="apg"``, DESIGN.md §15) at ``scale``.
 
     token (B,) -> (logits_hat (B,V) fp32, caches_c', caches_u').
-    ``combine_fn(l_u, l_c)``, when given, replaces Eq. 1 (the alternate
-    ``apg``/``interval`` combine modes, DESIGN.md §15).
     """
     emb = T.embed_tokens(params, cfg, token[:, None])
     h_c, caches_c = T.decode_step(params, cfg, emb, caches_c, pos,
@@ -63,9 +63,9 @@ def decode_step_full(params, cfg, token, caches_c, caches_u, pos, scale,
                                   rules=rules, long_ctx=long_ctx)
     l_c = T.unembed(params, cfg, h_c)[:, 0, :].astype(jnp.float32)
     l_u = T.unembed(params, cfg, h_u)[:, 0, :].astype(jnp.float32)
-    if combine_fn is not None:
-        return combine_fn(l_u, l_c), caches_c, caches_u
-    return cfg_combine(l_u, l_c, scale), caches_c, caches_u
+    logits = combine_guidance(l_u, l_c, scale, combine, apg_eta=apg_eta,
+                              apg_threshold=apg_threshold)
+    return logits, caches_c, caches_u
 
 
 def decode_step_cond(params, cfg, token, caches_c, pos, *, rules=None,
@@ -82,21 +82,21 @@ def guided_decode(params, cfg, prompt_tokens, plan: GuidancePlan, *,
                   rng=None, temperature: float = 0.0, rules=None,
                   long_ctx=False, capacity: int | None = None,
                   combine: str = "cfg", apg_eta: float = 0.0,
-                  apg_threshold: float = 0.0,
-                  interval: tuple[float, float] | None = None):
+                  apg_threshold: float = 0.0):
     """End-to-end guided generation: prefill both streams, then execute the
     plan's segments as separate scans (phase-split).
 
     prompt_tokens (B,S); ``plan.total_steps`` = number of new tokens.
     Returns (generated (B, n_new) int32, final position).
 
-    ``combine`` selects the FULL-step combine stage (DESIGN.md §15):
-    Eq. 1 (``"cfg"``), APG normalized guidance (``"apg"``, arxiv
-    2410.02416), or Eq. 1 at scale 1.0 outside ``interval`` (fractions of
-    the plan; ``"interval"``, arxiv 2404.07724).
+    ``combine`` selects the FULL-step combine stage (DESIGN.md §15): Eq. 1
+    (``"cfg"``) or APG normalized guidance (``"apg"``, arxiv 2410.02416).
+    A FULL segment combines at its own ``scale`` if the plan sets one (as
+    ``GuidancePlan.interval`` does), else at ``plan.guidance_scale``; the
+    first token, from the prefill, at step 0's.
     """
-    if combine not in ("cfg", "apg", "interval"):
-        raise ValueError(f"unknown combine mode {combine!r}")
+    if combine not in COMBINE_MODES:
+        raise ValueError(f"combine {combine!r} not in {COMBINE_MODES}")
     plan.validate_for_ar()
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     B, S = prompt_tokens.shape
@@ -113,35 +113,26 @@ def guided_decode(params, cfg, prompt_tokens, plan: GuidancePlan, *,
     caches_u = T.prepare_decode_caches(cfg, caches_u, seq_len=S, capacity=cap,
                                        long_ctx=long_ctx)
 
-    s = plan.guidance_scale
-    if combine == "interval":
-        iv = (0.0, 1.0) if interval is None else interval
-        a = round_half_up(n_new * iv[0])
-        b = round_half_up(n_new * iv[1])
+    def scale_of(seg):
+        return plan.guidance_scale if seg.scale is None else seg.scale
 
-    def combine_logits(l_u, l_c, i):
-        sc = s if combine != "interval" \
-            else jnp.where((i >= a) & (i < b), s, 1.0)
-        if combine == "apg":
-            return apg_combine(l_u, l_c, sc, eta=apg_eta,
-                               threshold=apg_threshold)
-        return cfg_combine(l_u, l_c, sc)
-
-    logits0 = cfg_combine(logits_u, logits_c, s) if combine == "cfg" \
-        else combine_logits(logits_u, logits_c, 0)
+    apg = dict(apg_eta=apg_eta, apg_threshold=apg_threshold)
+    logits0 = combine_guidance(logits_u, logits_c, scale_of(plan.segments[0]),
+                               combine, **apg)
     tok = _sample_token(logits0, jax.random.fold_in(rng, 0), temperature)
 
     outs = []
 
-    def full_body(carry, i):
-        tok, cc, cu = carry
-        logits, cc, cu = decode_step_full(
-            params, cfg, tok, cc, cu, S + i, s, rules=rules,
-            long_ctx=long_ctx,
-            combine_fn=None if combine == "cfg"
-            else (lambda l_u, l_c: combine_logits(l_u, l_c, i)))
-        nxt = _sample_token(logits, jax.random.fold_in(rng, 1 + i), temperature)
-        return (nxt, cc, cu), tok
+    def full_body(scale):
+        def body(carry, i):
+            tok, cc, cu = carry
+            logits, cc, cu = decode_step_full(
+                params, cfg, tok, cc, cu, S + i, scale, rules=rules,
+                long_ctx=long_ctx, combine=combine, **apg)
+            nxt = _sample_token(logits, jax.random.fold_in(rng, 1 + i),
+                                temperature)
+            return (nxt, cc, cu), tok
+        return body
 
     def cond_body(carry, i):
         tok, cc = carry
@@ -154,7 +145,7 @@ def guided_decode(params, cfg, prompt_tokens, plan: GuidancePlan, *,
         idx = jnp.arange(seg.start, seg.stop)
         if seg.mode is Mode.FULL:
             (tok, caches_c, caches_u), toks = jax.lax.scan(
-                full_body, (tok, caches_c, caches_u), idx)
+                full_body(scale_of(seg)), (tok, caches_c, caches_u), idx)
         else:
             (tok, caches_c), toks = jax.lax.scan(cond_body, (tok, caches_c), idx)
         outs.append(toks)

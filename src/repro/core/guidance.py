@@ -10,12 +10,18 @@ Properties the tests rely on:
 
 ``repro.kernels.cfg_combine`` is the fused Pallas TPU version of this exact
 op; this jnp form is its oracle and the XLA fallback.
+
+``combine_guidance`` is the one combine stage every executor calls on a
+FULL step: Eq. 1 (``"cfg"``) or APG (``"apg"``), at the scale the plan
+gives the step (DESIGN.md §15).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+COMBINE_MODES = ("cfg", "apg")
 
 
 def cfg_combine(eps_uncond, eps_cond, scale):
@@ -59,6 +65,18 @@ def apg_combine(eps_uncond, eps_cond, scale, *, eta: float = 0.0,
     from repro.kernels.cfg_combine import apg_combine_ref
     return apg_combine_ref(eps_uncond, eps_cond, scale, eta=eta,
                            threshold=threshold, diff=diff)
+
+
+def combine_guidance(eps_uncond, eps_cond, scale, mode: str = "cfg", *,
+                     apg_eta: float = 0.0, apg_threshold: float = 0.0,
+                     diff=None):
+    """The combine stage: APG for ``mode == "apg"`` (``diff``, a
+    momentum-averaged difference, applies to it only), else Eq. 1. The
+    callers check ``mode`` against ``COMBINE_MODES``."""
+    if mode == "apg":
+        return apg_combine(eps_uncond, eps_cond, scale, eta=apg_eta,
+                           threshold=apg_threshold, diff=diff)
+    return cfg_combine(eps_uncond, eps_cond, scale)
 
 
 def split_cond_uncond(batched):

@@ -93,8 +93,9 @@ class SDPipeline:
         """-> latents (B, latent_size, latent_size, C) in [-1, 1]-ish.
 
         ``combine_kw`` passes through to :func:`repro.core.sampler.sample`
-        (``combine=``, ``apg_eta=``, ``apg_threshold=``, ``apg_momentum=``,
-        ``interval=`` — the DESIGN.md §15 combine modes)."""
+        (``combine=``, ``apg_eta=``, ``apg_threshold=``, ``apg_momentum=``
+        — the DESIGN.md §15 combine stage); the plan gives each step its
+        mode and scale."""
         B = len(prompts)
         rng = jax.random.PRNGKey(seed)
         cond = self.encode_prompts(prompts)

@@ -14,11 +14,11 @@ stack can plan against (DESIGN.md §15):
   never exceeding the bound.
 
 ``static`` reproduces today's suffix plans bit for bit (the cursor IS a
-plain :class:`PlanCursor`).  ``interval`` (2404.07724) is structurally
-static in its pass schedule — FULL until the interval's stop fraction, COND
-after — but carries a per-step *effective scale* (1.0 outside the interval)
-for the combine stage.  ``divergence`` switches mid-flight: it feeds the
-per-step cond/uncond divergence norm through an EMA
+plain :class:`PlanCursor`).  ``interval`` (2404.07724) is static too: its
+bound plan is :meth:`GuidancePlan.interval`, whose segments give each step
+its mode and its combine scale (1.0 outside the interval), so the executors
+read the interval from the plan like any other.  ``divergence`` switches
+mid-flight: it feeds the per-step cond/uncond divergence norm through an EMA
 :class:`MomentumBuffer` (cf. the APG momentum buffer, arxiv 2410.02416)
 and drops the uncond stream as soon as the smoothed divergence falls below
 a threshold — the two streams have converged, so guidance no longer buys
@@ -30,8 +30,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from repro.core.selective import (GuidancePlan, Mode, PlanCursor, Segment,
-                                  round_half_up)
+from repro.core.selective import GuidancePlan, Mode, PlanCursor
 
 #: Policy names the serving stack accepts (``replay`` is sim/test-only —
 #: it needs a recorded switch step, which live traffic does not have).
@@ -136,11 +135,6 @@ class GuidancePolicy:
     def cursor(self, *, step: int = 0, passes_executed: int = 0) -> PlanCursor:
         raise NotImplementedError
 
-    def effective_scale(self, step: int) -> float:
-        """Combine-stage guidance scale for step ``step`` (interval policy
-        weakens guidance to 1.0 outside its interval; others are flat)."""
-        return self.plan.guidance_scale
-
 
 class StaticGuidancePolicy(GuidancePolicy):
     """Today's behavior: the realized schedule IS the bound plan.
@@ -205,40 +199,22 @@ class ReplayGuidancePolicy(GuidancePolicy):
                                  switch_step=switch_step, ema=ema)
 
 
-class IntervalGuidancePolicy(GuidancePolicy):
+class IntervalGuidancePolicy(StaticGuidancePolicy):
     """Interval guidance (Kynkäänniemi et al., arxiv 2404.07724), AR-legal.
 
-    Guidance is applied only for steps in ``[start, stop)`` (fractions of
-    ``total_steps``).  The AR-legal realization keeps both streams alive
-    through the whole pre-``stop`` prefix (the uncond KV cache must stay
-    fresh) but weakens the combine to scale 1.0 outside the interval; after
-    ``stop`` the uncond stream is dropped structurally, exactly like a
-    suffix plan.  The pass schedule is therefore static — no
-    ``policy_switch`` events — and the bound plan is exact.
+    The static policy over :meth:`GuidancePlan.interval`: both streams run
+    up to the interval's stop (the uncond KV cache must stay fresh),
+    combined at scale 1.0 before its start; after ``stop`` the uncond
+    stream is dropped structurally, exactly like a suffix plan.  No
+    ``policy_switch`` events, and the bound plan is exact.
     """
 
     name = "interval"
 
     def __init__(self, total_steps: int, start_frac: float, stop_frac: float,
                  guidance_scale: float = 7.5):
-        if not 0.0 <= start_frac < stop_frac <= 1.0:
-            raise ValueError((start_frac, stop_frac))
-        self.start = round_half_up(total_steps * start_frac)
-        self.stop = round_half_up(total_steps * stop_frac)
-        segs = []
-        if self.stop:
-            segs.append(Segment(0, self.stop, Mode.FULL))
-        if self.stop < total_steps:
-            segs.append(Segment(self.stop, total_steps, Mode.COND))
-        super().__init__(GuidancePlan(total_steps, tuple(segs), guidance_scale))
-
-    def cursor(self, *, step: int = 0, passes_executed: int = 0) -> PlanCursor:
-        return PlanCursor(self.plan, step=step, passes_executed=passes_executed)
-
-    def effective_scale(self, step: int) -> float:
-        if self.start <= step < self.stop:
-            return self.plan.guidance_scale
-        return 1.0
+        super().__init__(GuidancePlan.interval(total_steps, start_frac,
+                                               stop_frac, guidance_scale))
 
 
 def make_policy(name: str, plan: GuidancePlan, *,

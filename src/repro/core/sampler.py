@@ -7,12 +7,13 @@ segments run 1x batch and use the conditional eps directly. Because the
 partition is static, cond-only segments carry exactly half the denoiser
 FLOPs in the lowered HLO.
 
-Alternate combine modes (DESIGN.md §15): ``combine="apg"`` replaces Eq. 1
-on FULL steps with APG normalized/projected guidance (arxiv 2410.02416),
-optionally momentum-averaging the cond/uncond difference across steps
-(the EMA rides in the scan carry); ``combine="interval"`` weakens the
-guidance scale to 1.0 for steps outside ``interval`` (fractions of the
-plan, arxiv 2404.07724) while the pass schedule stays the plan's.
+A FULL segment combines at its own ``scale`` when the plan sets one (as
+``GuidancePlan.interval`` does, arxiv 2404.07724), else at the plan's
+``guidance_scale`` — a Python float either way. ``combine="apg"``
+replaces Eq. 1 with APG normalized/projected guidance (arxiv 2410.02416,
+DESIGN.md §15), optionally momentum-averaging the cond/uncond difference
+across steps: the EMA rides in the scan carry beside the latents, and is
+``None`` (an empty pytree) otherwise.
 
 Steppers: DDIM (eta=0, the paper's 50-step setting), Euler
 (probability-flow ODE) and ancestral DDPM over a ``NoiseSchedule``, and
@@ -34,12 +35,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.guidance import (apg_combine, cfg_combine, merge_cond_uncond,
-                                 split_cond_uncond)
+from repro.core.guidance import (COMBINE_MODES, combine_guidance,
+                                 merge_cond_uncond, split_cond_uncond)
 from repro.core.schedules import FlowSchedule, NoiseSchedule
-from repro.core.selective import GuidancePlan, Mode, round_half_up
-
-COMBINE_MODES = ("cfg", "apg", "interval")
+from repro.core.selective import GuidancePlan, Mode
 STEPPERS = ("ddim", "euler", "ddpm", "flow")
 STEP_SCOPES = {Mode.FULL: "sd.step.full", Mode.COND: "sd.step.cond"}
 
@@ -50,19 +49,6 @@ def _scoped(mode: Mode, body):
         with jax.named_scope(STEP_SCOPES[mode]):
             return body(carry, i)
     return run
-
-
-def _segment_scale(plan: GuidancePlan, combine: str,
-                   interval: tuple[float, float] | None):
-    """Per-step combine scale: the plan's flat scale, except under
-    interval guidance where steps outside [start, stop) run at 1.0."""
-    s = plan.guidance_scale
-    if combine != "interval":
-        return lambda i: s
-    iv = (0.0, 1.0) if interval is None else interval
-    a = round_half_up(plan.total_steps * iv[0])
-    b = round_half_up(plan.total_steps * iv[1])
-    return lambda i: jnp.where((i >= a) & (i < b), s, 1.0)
 
 
 def _step_coeffs(sched, num_steps: int):
@@ -83,10 +69,9 @@ def _step_coeffs(sched, num_steps: int):
 
 
 def _stepper(sched, num_steps: int, stepper: str, eta: float, rng):
-    """-> (timesteps, update(x, eps, i)): the one stepper dispatch of
-    ``sample`` and ``sample_trajectory``. The update runs under the named
-    scope ``sd.update``; a stochastic one draws its noise from
-    ``fold_in(rng, i)``."""
+    """-> (timesteps, update(x, eps, i)): the stepper dispatch of
+    ``sample``. The update runs under the named scope ``sd.update``; a
+    stochastic one draws its noise from ``fold_in(rng, i)``."""
     if stepper not in STEPPERS:
         raise ValueError(f"stepper {stepper!r} not in {STEPPERS}")
     if (stepper == "flow") != isinstance(sched, FlowSchedule):
@@ -169,7 +154,6 @@ def sample(
     apg_eta: float = 0.0,
     apg_threshold: float = 0.0,
     apg_momentum: float = 0.0,
-    interval: tuple[float, float] | None = None,
 ):
     """Run the guided denoising loop under ``plan``. Returns final latents."""
     if combine not in COMBINE_MODES:
@@ -177,89 +161,40 @@ def sample(
     ts, update = _stepper(sched, plan.total_steps, stepper, eta, rng)
     B = x_init.shape[0]
     text2 = merge_cond_uncond(cond_emb, uncond_emb)
-    step_scale = _segment_scale(plan, combine, interval)
 
-    def combine_eps(e_u, e_c, i, diff=None):
-        with jax.named_scope("sd.combine"):
-            if combine == "apg":
-                return apg_combine(e_u, e_c, step_scale(i), eta=apg_eta,
-                                   threshold=apg_threshold, diff=diff)
-            return cfg_combine(e_u, e_c, step_scale(i))
-
-    def full_step(x, i):
-        t2 = jnp.broadcast_to(ts[i], (2 * B,))
-        eps2 = eps_fn(merge_cond_uncond(x, x), t2, text2)
-        e_c, e_u = split_cond_uncond(eps2)
-        eps = combine_eps(e_u, e_c, i)
-        return update(x, eps, i), None
-
-    def cond_step(x, i):
-        t1 = jnp.broadcast_to(ts[i], (B,))
-        eps = eps_fn(x, t1, cond_emb)
-        return update(x, eps, i), None
-
-    if combine == "apg" and apg_momentum != 0.0:
-        # the MomentumBuffer EMA rides in the scan carry (one running
-        # average per latent element) and flows untouched through COND
-        # segments — the stream is dead there, not the memory of it
-        def full_step_m(carry, i):
+    def full_step(scale):
+        def body(carry, i):
             x, avg = carry
             t2 = jnp.broadcast_to(ts[i], (2 * B,))
             eps2 = eps_fn(merge_cond_uncond(x, x), t2, text2)
             e_c, e_u = split_cond_uncond(eps2)
-            diff = (e_c.astype(jnp.float32) - e_u.astype(jnp.float32))
-            avg = diff + apg_momentum * avg
-            eps = combine_eps(e_u, e_c, i, diff=avg)
+            if avg is not None:
+                avg = (e_c.astype(jnp.float32) - e_u.astype(jnp.float32)
+                       + apg_momentum * avg)
+            with jax.named_scope("sd.combine"):
+                eps = combine_guidance(e_u, e_c, scale, combine,
+                                       apg_eta=apg_eta,
+                                       apg_threshold=apg_threshold, diff=avg)
             return (update(x, eps, i), avg), None
+        return body
 
-        def cond_step_m(carry, i):
-            x, avg = carry
-            x, _ = cond_step(x, i)
-            return (x, avg), None
-
-        carry = (x_init, jnp.zeros(x_init.shape, jnp.float32))
-        for seg in plan.segments:
-            body = full_step_m if seg.mode is Mode.FULL else cond_step_m
-            carry, _ = jax.lax.scan(_scoped(seg.mode, body), carry,
-                                    jnp.arange(seg.start, seg.stop))
-        return carry[0]
-
-    x = x_init
-    for seg in plan.segments:
-        body = full_step if seg.mode is Mode.FULL else cond_step
-        x, _ = jax.lax.scan(_scoped(seg.mode, body), x,
-                            jnp.arange(seg.start, seg.stop))
-    return x
-
-
-def sample_trajectory(eps_fn, plan, sched, x_init, cond_emb, uncond_emb, **kw):
-    """As ``sample`` but also returns per-segment-boundary latents (for the
-    window-placement analyses)."""
-    xs = [x_init]
-    x = x_init
-    for seg in plan.segments:
-        x = _run_segment(eps_fn, plan, sched, x, cond_emb, uncond_emb, seg, **kw)
-        xs.append(x)
-    return x, xs
-
-
-def _run_segment(eps_fn, plan, sched, x, cond_emb, uncond_emb, seg, *,
-                 stepper="ddim", eta=0.0, rng=None):
-    ts, update = _stepper(sched, plan.total_steps, stepper, eta, rng)
-    B = x.shape[0]
-    text2 = merge_cond_uncond(cond_emb, uncond_emb)
-    s = plan.guidance_scale
-
-    def full_step(x, i):
-        t2 = jnp.broadcast_to(ts[i], (2 * B,))
-        eps2 = eps_fn(merge_cond_uncond(x, x), t2, text2)
-        e_c, e_u = split_cond_uncond(eps2)
-        return update(x, cfg_combine(e_u, e_c, s), i), None
-
-    def cond_step(x, i):
+    def cond_step(carry, i):
+        # the momentum EMA flows untouched through COND segments — the
+        # stream is dead there, not the memory of it
+        x, avg = carry
         t1 = jnp.broadcast_to(ts[i], (B,))
-        return update(x, eps_fn(x, t1, cond_emb), i), None
+        eps = eps_fn(x, t1, cond_emb)
+        return (update(x, eps, i), avg), None
 
-    body = full_step if seg.mode is Mode.FULL else cond_step
-    x, _ = jax.lax.scan(body, x, jnp.arange(seg.start, seg.stop))
-    return x
+    momentum = combine == "apg" and apg_momentum != 0.0
+    avg = jnp.zeros(x_init.shape, jnp.float32) if momentum else None
+    carry = (x_init, avg)
+    for seg in plan.segments:
+        if seg.mode is Mode.FULL:
+            body = full_step(plan.guidance_scale if seg.scale is None
+                             else seg.scale)
+        else:
+            body = cond_step
+        carry, _ = jax.lax.scan(_scoped(seg.mode, body), carry,
+                                jnp.arange(seg.start, seg.stop))
+    return carry[0]

@@ -12,11 +12,16 @@ The partition is *static*: under jit each segment compiles to its own
 structural (visible in the lowered HLO), not a runtime branch — the
 TPU-native formulation of the paper's mechanism (DESIGN.md §2).
 
-``suffix_plan(T, fraction)`` is the paper's recommended policy (optimize the
-*last* ``fraction`` of iterations); ``window_plan`` reproduces the Figure-1
-ablation (optimization window anywhere in the loop). For autoregressive
-decoding only suffix plans are valid (the uncond KV cache goes stale once
-skipped — enforced here).
+A segment may also carry its own guidance ``scale``; ``None`` (every plan
+but ``interval``'s) means the executor's scale. The plan thus answers the
+one question of the system for every step: which mode, at which scale.
+
+``GuidancePlan.suffix(T, fraction)`` is the paper's recommended policy
+(optimize the *last* ``fraction`` of iterations); ``window`` reproduces the
+Figure-1 ablation (optimization window anywhere in the loop); ``interval``
+is interval guidance (arxiv 2404.07724). For autoregressive decoding only
+suffix plans are valid (the uncond KV cache goes stale once skipped —
+enforced here).
 """
 
 from __future__ import annotations
@@ -48,6 +53,7 @@ class Segment:
     start: int       # first step index (inclusive)
     stop: int        # last step index (exclusive)
     mode: Mode
+    scale: float | None = None   # FULL steps' scale; None: the executor's
 
     @property
     def length(self) -> int:
@@ -108,6 +114,27 @@ class GuidancePlan:
             segs.append(Segment(b, total_steps, Mode.FULL))
         return GuidancePlan(total_steps, tuple(segs), guidance_scale)
 
+    @staticmethod
+    def interval(total_steps: int, start_frac: float, stop_frac: float,
+                 guidance_scale: float = 7.5) -> "GuidancePlan":
+        """Interval guidance (Kynkäänniemi et al., arxiv 2404.07724):
+        guided only on steps ``[a, b)``, ``a``/``b`` the fractions' step
+        boundaries. Both streams run from step 0 — combined at scale 1.0
+        before ``a`` — so the uncond KV cache stays fresh and the plan is
+        AR-legal; from ``b`` the uncond pass drops, as in a suffix plan."""
+        if not 0.0 <= start_frac < stop_frac <= 1.0:
+            raise ValueError((start_frac, stop_frac))
+        a = round_half_up(total_steps * start_frac)
+        b = round_half_up(total_steps * stop_frac)
+        segs = []
+        if a:
+            segs.append(Segment(0, a, Mode.FULL, 1.0))
+        if b > a:
+            segs.append(Segment(a, b, Mode.FULL))
+        if b < total_steps:
+            segs.append(Segment(b, total_steps, Mode.COND))
+        return GuidancePlan(total_steps, tuple(segs), guidance_scale)
+
     # ---- properties ------------------------------------------------------
 
     @property
@@ -128,6 +155,12 @@ class GuidancePlan:
             elif seen_cond:
                 return False
         return True
+
+    def segment_at(self, i: int) -> Segment:
+        for seg in self.segments:
+            if seg.start <= i < seg.stop:
+                return seg
+        raise IndexError(i)
 
     def modes(self) -> list[Mode]:
         out = []
@@ -196,10 +229,7 @@ class PlanCursor:
         """Mode of the *next* step to execute."""
         if self.done:
             raise ValueError("cursor exhausted")
-        for seg in self.plan.segments:
-            if seg.start <= self.step < seg.stop:
-                return seg.mode
-        raise AssertionError("unreachable: plans are contiguous")
+        return self.plan.segment_at(self.step).mode
 
     @property
     def cost(self) -> int:
@@ -215,10 +245,7 @@ class PlanCursor:
         return self.mode is not self._mode_at(self.step - 1)
 
     def _mode_at(self, i: int) -> Mode:
-        for seg in self.plan.segments:
-            if seg.start <= i < seg.stop:
-                return seg.mode
-        raise IndexError(i)
+        return self.plan.segment_at(i).mode
 
     def remaining_passes(self) -> int:
         return sum(2 * (min(s.stop, self.plan.total_steps) - max(s.start, self.step))

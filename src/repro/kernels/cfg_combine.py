@@ -1,28 +1,21 @@
 """Fused guidance combiners — Pallas TPU kernels.
 
-Three combine modes, one per serve workload (``--combine {cfg,apg,interval}``,
-DESIGN.md §15):
-
 * ``cfg_combine_pallas`` — Eq. 1, ``eps_hat = u + s * (c - u)``, fp32, tiled
   over lanes-aligned VMEM blocks.  Purely memory-bound (3 streams, 1 FMA per
   element): the win over the unfused XLA form is eliminating the
-  intermediate ``(c - u)`` round-trip.
+  intermediate ``(c - u)`` round-trip.  Its oracle is the jnp form in
+  ``repro.core.guidance.cfg_combine``.
 * ``apg_combine_pallas`` — APG normalized/projected guidance (arxiv
   2410.02416): the cond/uncond difference is norm-clamped, split into
   components parallel/orthogonal to the conditional prediction, and only
   the orthogonal part guides at full strength.  Two passes over feature
   blocks: the first accumulates the per-row norms and dot, the second
-  writes.
-* ``cfg_combine_rowscale_pallas`` — Eq. 1 with a *per-row* scale, the fused
-  form of interval guidance (arxiv 2404.07724) where rows outside the
-  guidance interval run at scale 1.
+  writes.  Its oracle is ``apg_combine_ref`` below, which
+  ``repro.core.guidance`` also takes as the XLA path.
 
-``apg_combine_ref`` is the jnp oracle the kernel property tests compare
-against; ``repro.core.guidance`` re-exports it as the XLA path.
-
-Like the paged-decode kernels (``repro.kernels.ops``), ``interpret``
-defaults to platform detection: interpreted off-TPU (CPU CI), compiled on
-TPU.
+Both take the scale as a Python float.  Like the paged-decode kernels
+(``repro.kernels.ops``), ``interpret`` defaults to platform detection:
+interpreted off-TPU (CPU CI), compiled on TPU.
 """
 
 from __future__ import annotations
@@ -196,38 +189,4 @@ def apg_combine_pallas(eps_uncond, eps_cond, scale: float, *,
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32)] * 3,
         interpret=_interpret_default(interpret),
     )(u2, c2)
-    return out[:rows, :feat].reshape(orig_shape)
-
-
-def _rowscale_kernel(u_ref, c_ref, s_ref, o_ref):
-    u = u_ref[...].astype(jnp.float32)
-    c = c_ref[...].astype(jnp.float32)
-    s = s_ref[:, :1]
-    o_ref[...] = (u + s * (c - u)).astype(o_ref.dtype)
-
-
-def cfg_combine_rowscale_pallas(eps_uncond, eps_cond, scales, *,
-                                interpret: bool | None = None):
-    """Eq. 1 with a per-row guidance scale — the fused interval-guidance
-    combine (rows outside the interval carry scale 1).  ``scales`` is
-    ``(B,)``, one scale per leading-axis row."""
-    assert eps_uncond.shape == eps_cond.shape
-    orig_shape = eps_cond.shape
-    u2, c2 = _as_rows(eps_uncond), _as_rows(eps_cond)
-    rows, feat = c2.shape
-    assert scales.shape == (rows,), (scales.shape, rows)
-    br, rp, bf, fp = _row_blocks(rows, feat)
-    u2, c2 = _pad2(u2, rp, fp), _pad2(c2, rp, fp)
-    lanes = 128
-    s2 = jnp.broadcast_to(_pad2(scales.astype(jnp.float32)[:, None], rp, 1),
-                          (rp, lanes))
-    blk = pl.BlockSpec((br, bf), lambda i, j: (i, j))
-    out = pl.pallas_call(
-        _rowscale_kernel,
-        grid=(rp // br, fp // bf),
-        in_specs=[blk, blk, pl.BlockSpec((br, lanes), lambda i, j: (i, 0))],
-        out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct((rp, fp), eps_cond.dtype),
-        interpret=_interpret_default(interpret),
-    )(u2, c2, s2)
     return out[:rows, :feat].reshape(orig_shape)

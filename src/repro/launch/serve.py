@@ -22,6 +22,8 @@ import json
 import jax
 
 from repro.configs import get_config
+from repro.core.guidance import COMBINE_MODES
+from repro.core.policy import GUIDANCE_POLICIES
 from repro.data.prompts import PAPER_PROMPTS
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import layers as L
@@ -247,25 +249,25 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="continuous: write the run's event trace as "
                          "Chrome-trace JSON (DESIGN.md §13)")
-    ap.add_argument("--policy", choices=["static", "divergence", "interval"],
+    ap.add_argument("--policy", choices=GUIDANCE_POLICIES,
                     default="static",
                     help="continuous: runtime guidance policy (divergence = "
                          "drop the uncond stream when the EMA cond/uncond "
                          "divergence falls below --divergence-threshold; "
                          "interval = guidance only inside --interval, "
                          "DESIGN.md §15)")
-    ap.add_argument("--combine", choices=["cfg", "apg", "interval"],
+    ap.add_argument("--combine", choices=COMBINE_MODES,
                     default="cfg",
-                    help="continuous: FULL-step combine stage (Eq. 1, APG "
-                         "normalized guidance arxiv 2410.02416, or "
-                         "interval-gated Eq. 1 arxiv 2404.07724)")
+                    help="continuous: FULL-step combine stage (Eq. 1, or APG "
+                         "normalized guidance arxiv 2410.02416)")
     ap.add_argument("--divergence-threshold", type=float, default=0.0,
                     help="continuous --policy divergence: EMA divergence "
                          "level that triggers the FULL->COND switch")
     ap.add_argument("--interval", type=float, nargs=2, default=(0.0, 1.0),
                     metavar=("START", "STOP"),
                     help="continuous: guidance interval as fractions of the "
-                         "plan (with --policy interval / --combine interval)")
+                         "plan; configures --policy interval (arxiv "
+                         "2404.07724)")
     ap.add_argument("--replicas", type=int, default=1,
                     help="continuous: engine replicas behind the fleet "
                          "router; >1 routes the trace instead of serving "

@@ -32,7 +32,7 @@ cond-stream prompt KV copy-on-write.
 """
 
 from repro.serve.autotune import BudgetAutotuner
-from repro.serve.engine import COMBINE_MODES, TICK_MODES, ContinuousEngine
+from repro.serve.engine import TICK_MODES, ContinuousEngine
 from repro.serve.fleet import (FLEET_COUNTERS, ROUTE_POLICIES, FleetReport,
                                FleetRouter, ServeFleet, fleet_summary,
                                simulate_fleet)
@@ -58,7 +58,7 @@ from repro.serve.state import (ContentPrefixRegistry, HostPagePool,
                                stream_page_needs)
 
 __all__ = [
-    "ArrivalQueue", "BudgetAutotuner", "COMBINE_MODES",
+    "ArrivalQueue", "BudgetAutotuner",
     "ContentPrefixRegistry",
     "ContinuousEngine", "Event", "EventTrace", "FLEET_COUNTERS",
     "FleetReport", "FleetRouter", "HostPagePool",

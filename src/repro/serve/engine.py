@@ -84,11 +84,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ar_decode as AR
-from repro.core.guidance import apg_combine, cfg_combine
+from repro.core.guidance import COMBINE_MODES, combine_guidance
 from repro.core.policy import (GUIDANCE_POLICIES, DivergenceGuidancePolicy,
                                DynamicPlanCursor, GuidancePolicy, make_policy)
-from repro.core.selective import (GuidancePlan, Mode, PlanCursor,
-                                  round_half_up)
+from repro.core.selective import GuidancePlan, Mode, PlanCursor
 from repro.data.tokenizer import EOS, PAD, encode
 from repro.models import transformer as T
 from repro.serve.autotune import BudgetAutotuner
@@ -111,7 +110,6 @@ KV_DTYPES = ("bf16", "int8")
 RESERVATION_MODES = ("eager", "lazy")
 STEP_MODES = ("signature", "ragged")
 PREFIX_CACHE_MODES = ("length", "content")
-COMBINE_MODES = ("cfg", "apg", "interval")
 TICK_MODES = ("sync", "async")
 
 
@@ -130,18 +128,17 @@ _bucket = bucket_pow2
 
 
 class _SlotArrays:
-    """Host-side per-slot scalars (token, position, scale, ...)."""
+    """Host-side per-slot scalars (token, position, temperature, ...)."""
 
     def __init__(self, n: int):
         self.tok = np.zeros(n, np.int32)
         self.pos = np.zeros(n, np.int32)
-        self.scale = np.zeros(n, np.float32)
         self.temp = np.zeros(n, np.float32)
         self.lstep = np.zeros(n, np.int32)
         self.key = np.zeros((n, 2), np.uint32)
 
     def permute(self, src: np.ndarray) -> None:
-        for name in ("tok", "pos", "scale", "temp", "lstep", "key"):
+        for name in ("tok", "pos", "temp", "lstep", "key"):
             arr = getattr(self, name)
             setattr(self, name, arr[src].copy())
 
@@ -374,11 +371,6 @@ class ContinuousEngine:
         if not 0.0 <= interval[0] < interval[1] <= 1.0:
             raise ValueError(f"interval {interval!r} must satisfy "
                              "0 <= start < stop <= 1")
-        if guidance_policy == "interval" and combine == "cfg":
-            # the interval policy's semantics live in the combine stage
-            # (scale 1.0 outside [start, stop)); plain cfg would silently
-            # degrade it to a static suffix plan
-            combine = "interval"
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
@@ -796,8 +788,8 @@ class ContinuousEngine:
             base = GuidancePlan.suffix(total, frac, req.guidance_scale)
         # the *bound* plan (DESIGN.md §15): what admission, reservation and
         # the pass budget price — a guaranteed upper bound on FULL steps.
-        # Static/divergence bind the base plan unchanged; interval rederives
-        # the FULL prefix from its stop fraction.
+        # Static/divergence bind the base plan unchanged; interval binds
+        # GuidancePlan.interval, whose segments also carry the scales.
         return self._policy_for(base).bound_plan()
 
     def _policy_for(self, plan: GuidancePlan) -> GuidancePolicy:
@@ -820,28 +812,21 @@ class ContinuousEngine:
         return policy.cursor(step=step, passes_executed=passes)
 
     def _eff_scale(self, uid: str, lstep: int | None = None) -> np.float32:
-        """Combine-stage guidance scale for ``uid``'s next sample. Flat
-        except under interval combine, where guidance weakens to 1.0 for
-        steps outside ``[start, stop)`` (arxiv 2404.07724)."""
+        """Combine-stage guidance scale for ``uid``'s step ``lstep`` (default:
+        its next): the bound plan's segment scale where it sets one (the
+        interval policy's 1.0 before its start), else the request's."""
         state = self._states[uid]
-        if self.combine != "interval":
-            return np.float32(state.req.guidance_scale)
         if lstep is None:
             lstep = int(self._slots.lstep[state.slot])
-        total = state.cursor.plan.total_steps
-        a = round_half_up(total * self.interval[0])
-        b = round_half_up(total * self.interval[1])
-        return np.float32(state.cursor.plan.guidance_scale
-                          if a <= lstep < b else 1.0)
+        scale = state.cursor.plan.segment_at(lstep).scale
+        return np.float32(state.req.guidance_scale if scale is None
+                          else scale)
 
     def _combine(self, l_u, l_c, scale):
-        """The configured combine stage: Eq. 1 (``cfg``/``interval`` — the
-        interval semantics live in the per-step scale) or APG normalized/
-        projected guidance (``apg``, arxiv 2410.02416)."""
-        if self.combine == "apg":
-            return apg_combine(l_u, l_c, scale, eta=self.apg_eta,
-                               threshold=self.apg_threshold)
-        return cfg_combine(l_u, l_c, scale)
+        """The configured combine stage (Eq. 1 or APG, DESIGN.md §15)."""
+        return combine_guidance(l_u, l_c, scale, self.combine,
+                                apg_eta=self.apg_eta,
+                                apg_threshold=self.apg_threshold)
 
     def _prompt_len_for(self, req: ServeRequest) -> int:
         S = self.prompt_len if req.prompt_len is None else req.prompt_len
@@ -883,7 +868,6 @@ class ContinuousEngine:
             key = np.asarray(jax.random.fold_in(self._base_key, self._req_seq))
             self._req_seq += 1
             self._slots.pos[slot] = self.prompt_len
-            self._slots.scale[slot] = req.guidance_scale
             self._slots.temp[slot] = req.temperature
             self._slots.lstep[slot] = 0
             self._slots.key[slot] = key
@@ -1046,7 +1030,6 @@ class ContinuousEngine:
         self.scheduler.admit(req.uid, slot, cursor, arrival=req.arrival,
                              deadline=req.deadline, priority=req.priority)
         self._slots.pos[slot] = pos
-        self._slots.scale[slot] = req.guidance_scale
         self._slots.temp[slot] = req.temperature
         return slot
 
@@ -1881,7 +1864,6 @@ class ContinuousEngine:
             else a[real].copy()
         return (jnp.asarray(idx), jnp.asarray(gather(self._slots.tok)),
                 jnp.asarray(gather(self._slots.pos)),
-                jnp.asarray(gather(self._slots.scale)),
                 jnp.asarray(gather(self._slots.temp)),
                 jnp.asarray(gather(self._slots.key)),
                 jnp.asarray(gather(self._slots.lstep)))
@@ -1902,15 +1884,13 @@ class ContinuousEngine:
             return self._execute_ragged(plan)
         nf_b = _bucket(plan.n_full) if self.bucket else plan.n_full
         nc_b = _bucket(plan.n_cond) if self.bucket else plan.n_cond
-        f_idx, f_tok, f_pos, f_scale, f_temp, f_key, f_lstep = \
+        f_idx, f_tok, f_pos, f_temp, f_key, f_lstep = \
             self._group_arrays(plan.full, nf_b)
-        c_idx, c_tok, c_pos, _c_scale, c_temp, c_key, c_lstep = \
+        c_idx, c_tok, c_pos, c_temp, c_key, c_lstep = \
             self._group_arrays(plan.cond, nc_b)
-        if self.combine == "interval":
-            # per-step effective scale: 1.0 outside [start, stop)
-            eff = [float(self._eff_scale(e.uid)) for e in plan.full]
-            f_scale = jnp.asarray(np.asarray(
-                eff + [0.0] * (nf_b - len(eff)), np.float32))
+        f_scale = np.zeros(nf_b, np.float32)
+        f_scale[:plan.n_full] = [self._eff_scale(e.uid) for e in plan.full]
+        f_scale = jnp.asarray(f_scale)
         if self.kv == "paged":
             fn = self._paged_step_fn(nf_b, nc_b)
             self._pool_p, f_next, c_next, f_div = fn(
@@ -1994,8 +1974,7 @@ class ContinuousEngine:
             bt[r] = self.pages.table(pr.entry.uid, pr.stream, self.nb_max)
             tok[r] = self._slots.tok[slot]
             pos[r] = self._slots.pos[slot]
-            scale[r] = self._eff_scale(pr.entry.uid) \
-                if self.combine == "interval" else self._slots.scale[slot]
+            scale[r] = self._eff_scale(pr.entry.uid)
             temp[r] = self._slots.temp[slot]
             rkey[r] = self._slots.key[slot]
             lstep[r] = self._slots.lstep[slot]

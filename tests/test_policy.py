@@ -8,9 +8,12 @@ Four layers:
   ``policy.max_full_steps()``, the switch fires exactly once, elided-pass
   accounting balances executed + elided == bound, and the static policy's
   cursor is a plain :class:`PlanCursor` walking the plan bit for bit.
-* **combine kernels** — APG (arxiv 2410.02416) and per-row interval
-  scaling pallas kernels vs their jnp oracles (interpret mode on CPU),
-  including the ragged self-pairing edge (u == c rows return c exactly).
+* **interval plans** — :meth:`GuidancePlan.interval` (arxiv 2404.07724)
+  gives each step its mode and combine scale; the engine under the
+  interval policy decodes the tokens ``guided_decode`` does on its plan.
+* **combine kernels** — the APG pallas kernel (arxiv 2410.02416) vs its
+  jnp oracle (interpret mode on CPU), including the ragged self-pairing
+  edge (u == c rows return c exactly).
 * **checkpoint-state reclaim regressions** — the uncond reclaim trigger
   is driven by checkpointed state, not the previous event's mode: a
   request preempted exactly at its FULL→COND boundary reclaims its uncond
@@ -30,12 +33,13 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.configs import get_smoke_config
+from repro.core.ar_decode import guided_decode
 from repro.core.policy import (DivergenceGuidancePolicy, DynamicPlanCursor,
                                IntervalGuidancePolicy, ReplayGuidancePolicy,
                                StaticGuidancePolicy, make_policy)
-from repro.core.selective import GuidancePlan, Mode, PlanCursor
-from repro.kernels.cfg_combine import (apg_combine_pallas, apg_combine_ref,
-                                       cfg_combine_rowscale_pallas)
+from repro.core.selective import GuidancePlan, Mode, PlanCursor, Segment
+from repro.data.tokenizer import encode
+from repro.kernels.cfg_combine import apg_combine_pallas, apg_combine_ref
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.serve import (ContinuousEngine, ServeRequest, SimRequest,
@@ -174,15 +178,13 @@ def test_observe_fires_exactly_once_and_respects_boundary():
 
 
 def test_interval_policy_bound_plan_and_scale():
-    """Interval guidance (arxiv 2404.07724): FULL until the stop fraction
-    (AR-legal — uncond KV must stay fresh), scale 1.0 outside the
-    interval, and a static pass schedule (plain PlanCursor)."""
+    """Interval guidance (arxiv 2404.07724): the bound plan is
+    ``GuidancePlan.interval`` — FULL until the stop fraction (AR-legal —
+    uncond KV must stay fresh), its segments carrying the scales — and a
+    static pass schedule (plain PlanCursor)."""
     pol = IntervalGuidancePolicy(10, 0.2, 0.7, guidance_scale=5.0)
-    assert pol.plan.segments[0] == \
-        pol.plan.segments[0].__class__(0, 7, Mode.FULL)
+    assert pol.plan == GuidancePlan.interval(10, 0.2, 0.7, 5.0)
     assert pol.max_full_steps() == 7
-    assert [pol.effective_scale(i) for i in range(10)] == \
-        [1.0, 1.0, 5.0, 5.0, 5.0, 5.0, 5.0, 1.0, 1.0, 1.0]
     assert type(pol.cursor()) is PlanCursor
 
     made = make_policy("interval", GuidancePlan.suffix(10, 0.5, 5.0),
@@ -192,6 +194,31 @@ def test_interval_policy_bound_plan_and_scale():
         make_policy("nope", GuidancePlan.full(4))
     with pytest.raises(ValueError):
         DivergenceGuidancePolicy(GuidancePlan.full(4), threshold=0.0)
+
+
+@pytest.mark.parametrize("total,start,stop,segments", [
+    (10, 0.2, 0.7, [(0, 2, Mode.FULL, 1.0), (2, 7, Mode.FULL, None),
+                    (7, 10, Mode.COND, None)]),
+    (10, 0.0, 0.5, [(0, 5, Mode.FULL, None), (5, 10, Mode.COND, None)]),
+    (10, 0.3, 1.0, [(0, 3, Mode.FULL, 1.0), (3, 10, Mode.FULL, None)]),
+    (8, 0.0, 1.0, [(0, 8, Mode.FULL, None)]),
+    (4, 0.2, 0.3, [(0, 1, Mode.FULL, 1.0), (1, 4, Mode.COND, None)]),
+])
+def test_interval_plan_segments(total, start, stop, segments):
+    """The one copy of the interval arithmetic: FULL at scale 1.0 before
+    ``round_half_up(T * start)``, FULL at the plan's scale up to
+    ``round_half_up(T * stop)``, COND after — a suffix plan, so AR-legal.
+    Boundaries that round together leave out the empty guided segment."""
+    plan = GuidancePlan.interval(total, start, stop, 5.0)
+    assert plan.segments == tuple(Segment(*seg) for seg in segments)
+    assert plan.guidance_scale == 5.0
+    plan.validate_for_ar()
+    for i in range(total):
+        assert plan.segment_at(i).start <= i < plan.segment_at(i).stop
+    with pytest.raises(IndexError):
+        plan.segment_at(total)
+    with pytest.raises(ValueError):
+        GuidancePlan.interval(total, stop, start)
 
 
 # ---------------------------------------------------------------------------
@@ -234,35 +261,12 @@ def test_apg_self_paired_rows_return_cond_exactly():
                                                   threshold=1.0))).all()
 
 
-@settings(max_examples=25, deadline=None)
-@given(st.integers(min_value=1, max_value=8),
-       st.integers(min_value=3, max_value=260),
-       st.integers(min_value=0, max_value=2**31 - 1))
-def test_rowscale_kernel_matches_per_row_eq1(rows, feat, seed):
-    """The fused interval combine: per-row Eq. 1, rows outside the
-    interval carrying scale 1.0 (identity on the cond stream)."""
-    rng = jax.random.PRNGKey(seed)
-    u = jax.random.normal(rng, (rows, feat), jnp.float32)
-    c = jax.random.normal(jax.random.fold_in(rng, 1), (rows, feat),
-                          jnp.float32)
-    scales = jax.random.uniform(jax.random.fold_in(rng, 2), (rows,),
-                                jnp.float32, 0.0, 8.0)
-    out = cfg_combine_rowscale_pallas(u, c, scales, interpret=True)
-    ref = u + scales[:, None] * (c - u)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    ones = jnp.ones((rows,), jnp.float32)
-    np.testing.assert_allclose(
-        np.asarray(cfg_combine_rowscale_pallas(u, c, ones, interpret=True)),
-        np.asarray(c), rtol=2e-6, atol=2e-6)
-
-
 @pytest.mark.parametrize("rows,feat", [(2, 2049), (8, 4500), (13, 260),
                                        (20, 4097)])
 def test_combine_kernels_tile_rows_and_features(rows, feat):
     """Several row blocks of 8 and several feature blocks: the
     APG reductions accumulate across feature blocks, padded rows and
-    lanes are sliced off, and both kernels still match their oracles."""
+    lanes are sliced off, and the kernel still matches its oracle."""
     rng = jax.random.PRNGKey(rows * 1000 + feat)
     u = jax.random.normal(rng, (rows, feat), jnp.float32)
     c = jax.random.normal(jax.random.fold_in(rng, 1), (rows, feat),
@@ -273,11 +277,6 @@ def test_combine_kernels_tile_rows_and_features(rows, feat):
         ref = apg_combine_ref(u, c, 4.0, eta=0.3, threshold=threshold)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
-    scales = jnp.linspace(0.5, 8.0, rows, dtype=jnp.float32)
-    out = cfg_combine_rowscale_pallas(u, c, scales, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(u + scales[:, None] * (c - u)),
-                               rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -482,3 +481,27 @@ def test_engine_divergence_elides_and_matches_sim(small_model):
     assert m.trace.keys() == sim_m.trace.keys()
     assert sim_m.policy_switches == m.policy_switches
     assert sim_m.uncond_passes_elided_dynamic == m.uncond_passes_elided_dynamic
+
+
+def test_engine_interval_policy_matches_guided_decode(small_model):
+    """The paged, ragged engine under the interval policy asks each row's
+    bound-plan segment for its scale: its greedy tokens are the ones
+    ``guided_decode`` produces on ``policy.bound_plan()`` — scale 1.0
+    before the interval, the request's scale inside it, COND after."""
+    cfg, params = small_model
+    total, scale = 10, 4.0
+    prompts = [f"interval req {i}" for i in range(3)]
+    eng = ContinuousEngine(params, cfg, num_slots=3, pass_budget=6,
+                           prompt_len=8, max_new=total, stop_on_eos=False,
+                           kv="paged", page_size=4, num_pages=24,
+                           guidance_policy="interval", interval=(0.2, 0.7))
+    assert eng.step_mode == "ragged"
+    out = eng.serve([ServeRequest(uid=f"i{i}", prompt=p, max_new_tokens=total,
+                                  guidance_scale=scale)
+                     for i, p in enumerate(prompts)])
+    plan = IntervalGuidancePolicy(total, 0.2, 0.7, scale).bound_plan()
+    toks = np.asarray([encode(p, cfg.vocab_size, 8) for p in prompts],
+                      np.int32)
+    gen, _ = guided_decode(params, cfg, toks, plan, temperature=0.0)
+    assert [out[f"i{i}"] for i in range(3)] == np.asarray(gen).tolist()
+    assert eng.metrics.denoiser_passes == 3 * plan.denoiser_passes()

@@ -32,6 +32,15 @@ GOLDEN_EVERY_64TH = [-0.12977249920368195, 3.499136209487915,
                      -0.9347946643829346, 0.008105185814201832,
                      0.2658000588417053, 1.5194498300552368]
 GOLDEN_ABS_SUM, GOLDEN_SQ_SUM = 625.6311951109674, 1210.0445274315603
+# the same for APG with momentum 0.5, eta 0.3 and threshold 1.0, as the
+# program computed them with a separate momentum step body
+APG_M_KW = dict(combine="apg", apg_momentum=0.5, apg_eta=0.3,
+                apg_threshold=1.0)
+APG_M_EVERY_64TH = [-0.03701071813702583, 3.0569632053375244,
+                    -1.925444483757019, -1.1172035932540894,
+                    -1.0686893463134766, 0.23295806348323822,
+                    0.09617837518453598, 1.3915159702301025]
+APG_M_ABS_SUM, APG_M_SQ_SUM = 588.5171500622528, 1056.5005585583513
 
 
 @pytest.fixture(scope="module")
@@ -94,6 +103,16 @@ def test_latents_unchanged_by_scopes(tiny):
                                rtol=1e-6, atol=1e-6)
     assert np.abs(out).sum() == pytest.approx(GOLDEN_ABS_SUM, rel=1e-6)
     assert (out ** 2).sum() == pytest.approx(GOLDEN_SQ_SUM, rel=1e-6)
+
+
+def test_apg_momentum_latents_unchanged(tiny):
+    pipe, args = tiny
+    out = np.asarray(pipe.generate_jit(GuidancePlan.suffix(4, 0.5, 4.0),
+                                       **APG_M_KW)(*args), np.float64)
+    np.testing.assert_allclose(out.reshape(-1)[::64], APG_M_EVERY_64TH,
+                               rtol=1e-6, atol=1e-6)
+    assert np.abs(out).sum() == pytest.approx(APG_M_ABS_SUM, rel=1e-6)
+    assert (out ** 2).sum() == pytest.approx(APG_M_SQ_SUM, rel=1e-6)
 
 
 def test_weights_enter_as_arguments(tiny):

@@ -26,8 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import flash_attention as FA
-from repro.kernels.cfg_combine import (apg_combine_pallas, cfg_combine_pallas,
-                                       cfg_combine_rowscale_pallas)
+from repro.kernels.cfg_combine import apg_combine_pallas, cfg_combine_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_decode_attention import (
     ragged_paged_decode_attention_int8_pallas,
@@ -99,14 +98,6 @@ def test_apg_combine_compiles(one_chip, rows):
                                              threshold=2.0, interpret=False),
              one_chip, ((rows, VOCAB), jnp.float32),
              ((rows, VOCAB), jnp.float32))
-
-
-@pytest.mark.parametrize("rows", [2, 8, 13])
-def test_rowscale_combine_compiles(one_chip, rows):
-    _compile(lambda u, c, s: cfg_combine_rowscale_pallas(u, c, s,
-                                                         interpret=False),
-             one_chip, ((rows, VOCAB), jnp.float32),
-             ((rows, VOCAB), jnp.float32), ((rows,), jnp.float32))
 
 
 def test_flash_attention_compiles(one_chip):
